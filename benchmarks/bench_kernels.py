@@ -22,6 +22,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 from repro.core import backends, builder, models, snn
 from repro.core.autotune import autotune_report
 from repro.core.layout import blocked_layout
+from repro.utils.compile_cache import use_compile_cache
 
 
 def bench_sweep_sizes(out, *, quick=False):
@@ -137,6 +138,7 @@ if __name__ == "__main__":
                     help="print only the chosen (PB, EB) table vs the "
                          "fixed defaults (repro.core.autotune)")
     args = ap.parse_args()
+    use_compile_cache()
     print("name,us_per_call,derived")
     main(lambda name, us, derived="": print(f"{name},{us:.2f},{derived}",
                                             flush=True),

@@ -51,6 +51,7 @@ from repro.core.distributed import (DistributedConfig, init_stacked_state,
                                     make_distributed_step, mesh_decompose,
                                     prepare_stacked, wire_bytes_per_step,
                                     wire_bytes_split)
+from repro.utils.compile_cache import use_compile_cache
 
 DEFAULT_BACKENDS = available_backends()
 DEFAULT_WIRES = ("f32", "u8", "packed", "sparse")
@@ -189,7 +190,6 @@ def _bench_profile_exchange(out, reps):
     the two-level spike exchange on whatever host mesh exists."""
     from jax.sharding import PartitionSpec as P
     from repro.core.distributed import _exchange
-    from repro.utils.jax_compat import shard_map
 
     n_dev = jax.device_count()
     width = 2 if n_dev % 2 == 0 else 1
@@ -220,9 +220,9 @@ def _bench_profile_exchange(out, reps):
             return mirror[None]
 
         spec_p = P(("data", "model"))
-        ex = jax.jit(shard_map(local, mesh=mesh,
-                               in_specs=(spec_p, spec_p),
-                               out_specs=spec_p))
+        ex = jax.jit(jax.shard_map(local, mesh=mesh,
+                                   in_specs=(spec_p, spec_p),
+                                   out_specs=spec_p, check_vma=False))
         out(f"snn_profile/exchange/{mode}-packed",
             _time(ex, (bits, consts), reps),
             dict(phase="exchange", comm_mode=mode, mesh=f"{rows}x{width}",
@@ -561,7 +561,9 @@ def bench_build_scaling(out, *, quick=False):
     scales = (0.1, 0.3) if quick else (0.1, 0.3, 0.6)
     src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
                                        "src"))
-    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+    # the builds are host-only: the children stay off the accelerator,
+    # which this process may already hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
         p for p in (src, os.environ.get("PYTHONPATH")) if p))
     import subprocess
     for scale in scales:
@@ -806,6 +808,8 @@ def bench_sessions(out, *, quick=False, n_sessions=8):
         p for p in (src, os.environ.get("PYTHONPATH")) if p))
     seq_s = 0.0       # wall-clock of the one-shot processes
     seq_compute = 0.0  # post-import build+compile+run inside the child
+    # each one-shot child takes the device in turn, so they all run before
+    # this process touches it (an accelerator takes one process at a time)
     for seed in range(n_sessions):
         t0 = time.perf_counter()
         r = subprocess.run(
@@ -950,6 +954,7 @@ if __name__ == "__main__":
                     help="write records (incl. wire bytes/step) as JSON; "
                          "'' disables")
     args = ap.parse_args()
+    use_compile_cache()
     # fail fast, before the step-scaling phase runs
     neuron_models_mod.get_model(args.model)
     if args.backend:

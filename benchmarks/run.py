@@ -44,6 +44,13 @@ def _bench_json(path: str, scale: str) -> None:
         _out(name, us, derived or {})
 
     print("name,us_per_call,derived")
+    # multi-tenant serving throughput: N resident sessions in ONE vmapped
+    # slot batch vs N sequential one-shot runs (DESIGN.md §16);
+    # diff.py holds the batched speedup_vs_sequential above its floor.
+    # First, because each one-shot run is a child process that needs the
+    # device, and the device serves one process: this one must not hold it
+    # yet.
+    bench_snn.bench_sessions(out, quick=quick)
     # per-phase hot path (every backend incl. pallas:sparse) + the
     # dense-vs-gated activity axis (the pallas:sparse acceptance metric)
     bench_snn.bench_profile(out, quick=quick)
@@ -75,10 +82,6 @@ def _bench_json(path: str, scale: str) -> None:
     # step overhead + naive vs checkpointed rollout gradient peak memory;
     # diff.py holds checkpointed temp bytes strictly below naive at T=200
     bench_snn.bench_surrogate(out, quick=quick)
-    # multi-tenant serving throughput: N resident sessions in ONE vmapped
-    # slot batch vs N sequential one-shot runs (DESIGN.md §16);
-    # diff.py holds the batched speedup_vs_sequential above its floor
-    bench_snn.bench_sessions(out, quick=quick)
 
     payload = {
         "meta": {
@@ -109,6 +112,8 @@ def main() -> None:
                     help="baseline suite size for --json (quick: CI-sized; "
                          "full: committed-numbers geometry)")
     args = ap.parse_args()
+    from repro.utils.compile_cache import use_compile_cache
+    use_compile_cache()
 
     if args.json is not None:
         _bench_json(args.json or f"BENCH_{args.scale}.json", args.scale)

@@ -20,6 +20,7 @@ import time
 sys.path.insert(0, "src")
 
 from repro.diff import inverse
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
@@ -33,6 +34,7 @@ def main():
                     help="CI-sized fit: shorter rollouts, one profiled "
                          "round (~1 min)")
     args = ap.parse_args()
+    use_compile_cache()
 
     kwargs = {}
     if args.smoke:

@@ -22,6 +22,7 @@ from repro.data.pipeline import TokenPipeline
 from repro.models.model import build_model
 from repro.train.loop import make_train_step
 from repro.train.optimizer import init_opt_state
+from repro.utils.compile_cache import use_compile_cache
 
 
 def snn_demo():
@@ -61,6 +62,7 @@ def lm_demo():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     snn_demo()
     lm_demo()
     print("ok")

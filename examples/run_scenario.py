@@ -28,6 +28,7 @@ import numpy as np
 from repro.core import builder, engine, models
 from repro.core import distributed as dist
 from repro.core import neuron_models
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
@@ -52,6 +53,7 @@ def main():
                     help="execution backend (flat|bucketed|pallas)")
     ap.add_argument("--spike-wire", default="packed")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.model:
         spec, stdp = models.model_demo(args.model, scale=args.scale)

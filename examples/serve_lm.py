@@ -12,6 +12,7 @@ import jax
 from repro import configs
 from repro.models.model import build_model
 from repro.serve.engine import BatchServer
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
@@ -19,6 +20,7 @@ def main():
     ap.add_argument("--arch", default="qwen2.5-3b")
     ap.add_argument("--max-new", type=int, default=24)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = configs.get_smoke(args.arch)
     m = build_model(cfg)

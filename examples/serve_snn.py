@@ -28,6 +28,7 @@ import numpy as np
 from repro.core import models
 from repro.serve.sessions import Backpressure
 from repro.serve.snn import SessionEngine
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -44,6 +45,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None,
                     help="enables eviction (and slot over-subscription)")
     args = ap.parse_args()
+    use_compile_cache()
 
     slots = args.slots or args.sessions
     eng = SessionEngine(max_sessions=slots, sweep=args.sweep,

@@ -23,6 +23,7 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointManager
 from repro.core import builder, engine, models, snn
 from repro.core import distributed as dist
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
@@ -40,6 +41,7 @@ def main():
                          "inter-host hop on a host-aligned mesh); "
                          "default: same as --spike-wire")
     args = ap.parse_args()
+    use_compile_cache()
 
     spec = models.marmoset(scale=args.scale, n_areas=args.areas)
     n_dev = jax.device_count()

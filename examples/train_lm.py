@@ -25,6 +25,7 @@ from repro.data.pipeline import TokenPipeline
 from repro.models.model import build_model
 from repro.train.loop import make_train_step
 from repro.train.optimizer import init_opt_state
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
@@ -39,6 +40,7 @@ def main():
     ap.add_argument("--ckpt", default="/tmp/lm_ckpt")
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = ModelConfig(
         name=f"lm-{args.d_model}d{args.layers}L", family="dense",

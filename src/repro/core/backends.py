@@ -67,7 +67,8 @@ from repro.core import snn
 from repro.core import stdp as stdp_mod
 from repro.core.layout import BlockedGraph, blocked_layout
 from repro.kernels.stdp_update import stdp_update_kernel, stdp_update_worklist
-from repro.kernels.synaptic_gather import (blocked_reduce_sweep,
+from repro.kernels.synaptic_gather import (blocked_arrivals,
+                                           blocked_reduce_sweep,
                                            synaptic_gather)
 
 __all__ = ["EdgeLayout", "SweepBackend", "FlatBackend", "BucketedBackend",
@@ -123,20 +124,23 @@ def _device_blocked(bg: BlockedGraph) -> BlockedGraph:
 
     Done ONCE in ``prepare`` so traced sweep calls never re-``jnp.asarray``
     the constants (each call would re-stage a host->device transfer into
-    the jaxpr); build-time-only fields (weight) are dropped.
+    the jaxpr); build-time-only fields (weight) are dropped.  The arrays
+    are made eagerly even when ``prepare`` runs inside a trace (``jit`` of
+    ``engine.run``): the cache must never hold that trace's tracers.
     """
     as_j = lambda a, dt: (None if a is None
                           else jnp.asarray(np.asarray(a), dtype=dt))
-    return dataclasses.replace(
-        bg,
-        pre_idx=as_j(bg.pre_idx, jnp.int32),
-        post_rel=as_j(bg.post_rel, jnp.int32),
-        delay=as_j(bg.delay, jnp.int32),
-        channel=as_j(bg.channel, jnp.int32),
-        plastic=as_j(bg.plastic, jnp.bool_),
-        edge_perm=as_j(bg.edge_perm, jnp.int32),
-        weight=None,
-    )
+    with jax.ensure_compile_time_eval():
+        return dataclasses.replace(
+            bg,
+            pre_idx=as_j(bg.pre_idx, jnp.int32),
+            post_rel=as_j(bg.post_rel, jnp.int32),
+            delay=as_j(bg.delay, jnp.int32),
+            channel=as_j(bg.channel, jnp.int32),
+            plastic=as_j(bg.plastic, jnp.bool_),
+            edge_perm=as_j(bg.edge_perm, jnp.int32),
+            weight=None,
+        )
 
 
 def _accumulate(layout: EdgeLayout, weights, arrived):
@@ -522,9 +526,9 @@ class PallasBackend(SweepBackend):
 
     The blocked layout is the RESIDENT hot-path representation: run-time
     weights live in ELL slot order ((NB*EB,)) in engine/distributed state,
-    the sweep kernel emits the per-edge arrivals from its own fused ring
-    gather (one edge pass per step - no second ring gather for STDP, no
-    per-step ``edge_perm`` re-gather of weights), and the STDP kernel
+    the sweep returns the per-edge arrivals its reduction consumed (one
+    ring gather per step - no second one for STDP, no per-step
+    ``edge_perm`` re-gather of weights), and the STDP kernel
     consumes the blocked arrivals/weights directly with block-relative post
     rows.  ``edge_perm`` conversions run only at build, checkpoint and
     telemetry boundaries.
@@ -677,11 +681,10 @@ class SparsePallasBackend(PallasBackend):
 
     At biological rates only a few percent of neurons spike per step, yet
     the dense kernel touches every ELL slot of every post block every step.
-    This backend runs a cheap jnp pre-pass that reproduces the fused
-    kernel's ring/fresh gather bit-for-bit (same flat-take, same fresh
-    overlay, same padding mask - (NB, EB) blocked arrivals), counts the
-    per-block arrival population, and compacts the ACTIVE block ids into a
-    fixed-capacity worklist:
+    This backend runs the dense path's own arrivals gather
+    (:func:`repro.kernels.synaptic_gather.blocked_arrivals` - (NB, EB)
+    blocked arrivals), counts the per-block arrival population, and
+    compacts the ACTIVE block ids into a fixed-capacity worklist:
 
     * capacity comes from the same firing-rate headroom policy as the
       ``sparse:<rate>`` wire (:func:`repro.core.autotune.gate_capacity`);
@@ -750,22 +753,11 @@ class SparsePallasBackend(PallasBackend):
             min_capacity=self.min_capacity, signature=sig)
 
     def _blocked_arrivals(self, layout: EdgeLayout, ring, t, fresh):
-        """(NB, EB) f32 per-edge arrivals - the pre-pass.
-
-        Bit-identical to the fused kernel's in-kernel gather: same flat
-        ring take, same delay==1 fresh overlay, same delay>0 padding mask.
-        """
+        """(NB, EB) f32 per-edge arrivals - the pre-pass, the same gather
+        the dense kernel path runs (:func:`blocked_arrivals`)."""
         bg = _require_blocked(layout)
-        d, m = ring.shape
-        t = jnp.asarray(t, jnp.int32)
-        row = jnp.mod(t - bg.delay, layout.max_delay)
-        flat = ring.astype(jnp.float32).reshape(-1)
-        arrived = jnp.take(flat, row * m + bg.pre_idx, axis=0)
-        if fresh is not None:
-            fresh_arr = jnp.take(fresh.astype(jnp.float32).reshape(-1),
-                                 bg.pre_idx, axis=0)
-            arrived = jnp.where(bg.delay == 1, fresh_arr, arrived)
-        return arrived * (bg.delay > 0).astype(jnp.float32)
+        return blocked_arrivals(bg.pre_idx, bg.delay, ring, t,
+                                max_delay=layout.max_delay, fresh=fresh)
 
     def gate_stats(self, layout: EdgeLayout, ring, t, fresh=None):
         """(per-block arrival counts (NB,), n_active (), capacity) - the

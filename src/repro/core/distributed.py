@@ -65,9 +65,9 @@ from repro.core import wire as wire_mod
 from repro.core.builder import NetworkSpec, build_shards
 from repro.core.decomposition import (Decomposition, apportion_devices,
                                       multisection_divide)
-from repro.core.engine import DRIVE_SALT, EngineConfig, ShardGraph
+from repro.core.engine import (DRIVE_SALT, EngineConfig, ShardGraph,
+                               drive_threshold, poisson_events)
 from repro.core.layout import BlockedGraph, DEFAULT_PB
-from repro.utils.jax_compat import shard_map
 
 __all__ = ["mesh_decompose", "StackedNetwork", "prepare_stacked",
            "DistributedConfig", "make_distributed_step", "init_stacked_state",
@@ -575,7 +575,7 @@ class DistState:
     k_post: jax.Array
     prev_bits: jax.Array     # (S, n_local) spikes fired last step (raw)
     t: jax.Array             # (S,) step counter (identical values)
-    key: jax.Array           # (S, 2) per-shard PRNG key data
+    key: jax.Array           # (S, 2) PRNG key data, equal on every shard
     wire_overflow: jax.Array  # (S,) cumulative saturated lossy-wire payloads
     #: (S,) cumulative steps whose activity gate saturated its worklist and
     #: fell back to the dense sweep (DESIGN.md §13) - the compute twin of
@@ -613,7 +613,10 @@ jax.tree_util.register_dataclass(
 def init_stacked_state(net: StackedNetwork, groups, seed: int = 0,
                        dtype=jnp.float32, weight_dtype=None,
                        sweep: str | None = None,
-                       neuron_model: str = "lif") -> DistState:
+                       neuron_model: str = "lif",
+                       mesh: Mesh | None = None,
+                       axis_names: tuple[str, ...] = ("data", "model")
+                       ) -> DistState:
     """``weight_dtype`` may be narrower than the neuron dtype (bf16) for
     non-plastic evaluation runs - weights are the largest per-edge stream
     (§Perf C4).  ``sweep`` (a backend name) stores the weights in that
@@ -624,10 +627,16 @@ def init_stacked_state(net: StackedNetwork, groups, seed: int = 0,
     be that model's parameter class; model-specific state lands in
     ``DistState.aux``.
 
-    Multi-process nets (``net.local_slice``) hold only their own shards:
-    every state leaf then has that local leading dim, but the PRNG keys are
-    still the GLOBAL per-shard split sliced to the owned range - so the
-    trajectory is independent of how many processes build it."""
+    ``mesh`` places every leaf straight onto its devices, shard ``i`` on
+    the ``i``-th device of ``axis_names`` (the step's ``P(axis_names)``
+    layout); without it the leaves are plain single-device arrays.
+
+    Every shard carries the SAME PRNG key: the external drive draws one
+    stream per global neuron id from it (``engine.poisson_events``), so the
+    trajectory depends neither on how many shards nor on how many
+    processes run the network.  Multi-process nets (``net.local_slice``)
+    hold only their own shards: every state leaf then has that local
+    leading dim."""
     S = net.n_shards
     lo, hi = (0, S) if net.local_slice is None else net.local_slice
     model = neuron_models_mod.get_model(neuron_model)
@@ -635,14 +644,14 @@ def init_stacked_state(net: StackedNetwork, groups, seed: int = 0,
     Sl = gid.shape[0]
     assert Sl == hi - lo, (Sl, net.local_slice)
     nvars = model.init_vars(gid, list(groups))
-    keys = jax.random.split(jax.random.key(seed), S)[lo:hi]
+    key = np.asarray(jax.random.key_data(jax.random.key(seed)))
     drive_key = None
     if model.stochastic:
         # shard-independent drive stream (per-neuron via GLOBAL-id fold_in
         # inside the model) - the decomposition-invariance contract
         dk = jax.random.key_data(
             jax.random.fold_in(jax.random.key(seed), DRIVE_SALT))
-        drive_key = jnp.broadcast_to(dk, (Sl,) + dk.shape)
+        drive_key = np.broadcast_to(np.asarray(dk), (Sl,) + dk.shape)
     weights = np.asarray(net.graph["weight_init"])
     weights_layout = "flat"
     if sweep is not None and backends_mod.get_backend(
@@ -655,22 +664,29 @@ def init_stacked_state(net: StackedNetwork, groups, seed: int = 0,
         weights = np.take_along_axis(weights, perm, axis=1)
         nb, eb, pb = net.blocked_meta
         weights_layout = f"blocked:{pb}x{eb}"
+    if mesh is None:
+        put = jnp.asarray
+    else:
+        sharding = NamedSharding(mesh, P(axis_names))
+        put = lambda a: jax.device_put(a, sharding)
+    host = lambda a, dt: put(np.asarray(a, dtype=dt))
+    zeros = lambda shape, dt: put(np.zeros(shape, dt))
     return DistState(
-        v_m=jnp.asarray(nvars["v_m"], dtype),
-        syn_ex=jnp.asarray(nvars["syn_ex"], dtype),
-        syn_in=jnp.asarray(nvars["syn_in"], dtype),
-        ref_count=jnp.asarray(nvars["ref_count"], jnp.int32),
-        ring=jnp.zeros((Sl, net.max_delay, net.n_mirror), dtype),
-        weights=jnp.asarray(weights, weight_dtype or dtype),
-        k_pre=jnp.zeros((Sl, net.n_mirror), dtype),
-        k_post=jnp.zeros((Sl, net.n_local), dtype),
-        prev_bits=jnp.zeros((Sl, net.n_local), dtype),
-        t=jnp.zeros((Sl,), jnp.int32),
-        key=jax.random.key_data(keys),
-        wire_overflow=jnp.zeros((Sl,), jnp.int32),
-        gate_overflow=jnp.zeros((Sl,), jnp.int32),
-        drive_key=drive_key,
-        aux={k: jnp.asarray(nvars[k], dtype) for k in model.extra_fields},
+        v_m=host(nvars["v_m"], dtype),
+        syn_ex=host(nvars["syn_ex"], dtype),
+        syn_in=host(nvars["syn_in"], dtype),
+        ref_count=host(nvars["ref_count"], jnp.int32),
+        ring=zeros((Sl, net.max_delay, net.n_mirror), dtype),
+        weights=host(weights, weight_dtype or dtype),
+        k_pre=zeros((Sl, net.n_mirror), dtype),
+        k_post=zeros((Sl, net.n_local), dtype),
+        prev_bits=zeros((Sl, net.n_local), dtype),
+        t=zeros((Sl,), jnp.int32),
+        key=put(np.broadcast_to(key, (Sl,) + key.shape)),
+        wire_overflow=zeros((Sl,), jnp.int32),
+        gate_overflow=zeros((Sl,), jnp.int32),
+        drive_key=None if drive_key is None else put(drive_key),
+        aux={k: host(nvars[k], dtype) for k in model.extra_fields},
         weights_layout=weights_layout,
         neuron_model=model.name,
     )
@@ -881,14 +897,18 @@ def check_net_backend(net: StackedNetwork,
     return backend
 
 
-def stacked_consts(net: StackedNetwork, *, needs_blocked: bool) -> dict:
+def stacked_consts(net: StackedNetwork, *, needs_blocked: bool,
+                   dt: float) -> dict:
     """The (S, ...) host-side const arrays the sharded step consumes -
-    graph edge arrays plus the exchange metadata.  Device placement is the
-    caller's job (``jnp.asarray`` single-process; global sharded arrays in
+    graph edge arrays, the external drive's per-neuron thresholds for
+    step ``dt`` (:func:`repro.core.engine.drive_threshold`) and the
+    exchange metadata.  Device placement is the caller's job (sharded
+    ``device_put`` single-process; global sharded arrays in
     :mod:`repro.core.multihost`)."""
     consts = {k: v for k, v in net.graph.items()
               if needs_blocked or not k.startswith("blk_")}
     consts.update(
+        ext_thresh=drive_threshold(net.graph["ext_rate"], dt),
         boundary_slots=net.boundary_slots,
         mirror_is_intra=net.mirror_is_intra,
         mirror_row_gather=net.mirror_row_gather,
@@ -903,9 +923,10 @@ def make_distributed_step(net: StackedNetwork, mesh: Mesh,
                           cfg: DistributedConfig):
     """Build the jit-able sharded step: DistState -> (DistState, spike bits).
 
-    All graph/metadata arrays are closed over as device-axis-sharded
-    constants.  The returned function is shard_map'ed over the mesh and can
-    be scanned or called per-step.  (Single-process entry point; the
+    All graph/metadata arrays are closed over as constants sharded over
+    the mesh like the state (``P(cfg.axis_names)``).  The returned
+    function is shard_map'ed over the mesh and can be scanned or called
+    per-step.  (Single-process entry point; the
     multi-process twin is :func:`repro.core.multihost.make_multihost_step`,
     which shards the same consts across hosts.)
     """
@@ -914,8 +935,13 @@ def make_distributed_step(net: StackedNetwork, mesh: Mesh,
     smapped = _build_step(mesh, groups, cfg, net.max_delay, net.n_local,
                           net.n_mirror,
                           net.blocked_meta if needs_blocked else None)
-    consts = stacked_consts(net, needs_blocked=needs_blocked)
-    consts_j = {k: jnp.asarray(v) for k, v in consts.items()}
+    consts = stacked_consts(net, needs_blocked=needs_blocked,
+                            dt=cfg.engine.dt)
+    # shard i's consts live on shard i's device only (never all of them on
+    # device 0 first)
+    sharding = NamedSharding(mesh, P(cfg.axis_names))
+    consts_j = {k: jax.device_put(np.asarray(v), sharding)
+                for k, v in consts.items()}
 
     def step(state: DistState):
         return smapped(state, consts_j)
@@ -1006,10 +1032,10 @@ def _build_step(mesh: Mesh, groups, cfg: DistributedConfig, max_delay: int,
                 # per-shard split
                 mkey = jax.random.wrap_key_data(state.drive_key)
         if cfg.engine.external_drive:
-            lam = g["ext_rate"] * (cfg.engine.dt * 1e-3)
-            input_ex = input_ex + (g["ext_weight"]
-                                   * jax.random.poisson(sub, lam, (n_local,))
-                                   ).astype(dtype)
+            input_ex = input_ex + (
+                g["ext_weight"]
+                * poisson_events(sub, g["ext_thresh"], g["global_id"])
+            ).astype(dtype)
         neurons = snn.NeuronState(
             v_m=state.v_m, syn_ex=state.syn_ex, syn_in=state.syn_in,
             ref_count=state.ref_count,
@@ -1076,7 +1102,7 @@ def _build_step(mesh: Mesh, groups, cfg: DistributedConfig, max_delay: int,
         return expand(new_s), bits[None]
 
     state_specs = P(cfg.axis_names)
-    return shard_map(
+    return jax.shard_map(
         sharded_step, mesh=mesh,
         in_specs=(state_specs, state_specs),
-        out_specs=(state_specs, state_specs))
+        out_specs=(state_specs, state_specs), check_vma=False)

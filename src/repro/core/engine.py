@@ -279,10 +279,63 @@ def synaptic_sweep(graph: ShardGraph, weights: jax.Array, ring: jax.Array,
     return ex, inh, arrived
 
 
+#: largest expected external events per neuron and step the product
+#: method of :func:`poisson_events` takes (the scenarios use 1.5-5)
+MAX_DRIVE_EVENTS = 64.0
+
+
+def drive_threshold(ext_rate, dt: float) -> np.ndarray:
+    """``exp(-lam)`` per neuron, ``lam = ext_rate * dt`` the expected
+    external events per step, computed on the host in float64.
+
+    :func:`poisson_events` compares products of uniforms against it, so
+    no transcendental runs on the device: TPU and CPU ``exp``/``log``
+    differ in the last bits (up to ~4e-4 relative for ``log`` on a v5e),
+    which flips the occasional event count of ``jax.random.poisson``.
+    ``ext_rate`` must be concrete; a traced (fitted) rate takes
+    ``external_drive_mode="diffusion"``.
+    """
+    lam = np.asarray(ext_rate, np.float64) * (dt * 1e-3)
+    if lam.size and lam.max() > MAX_DRIVE_EVENTS:
+        raise ValueError(
+            f"the poisson drive expects at most {MAX_DRIVE_EVENTS} events "
+            f"per neuron and step (exp(-lam) must stay a normal float32), "
+            f"got {lam.max():g}; use external_drive_mode='diffusion'")
+    return np.exp(-lam).astype(np.float32)
+
+
+def poisson_events(key, thresh, gid):
+    """Poisson event counts, one stream per GLOBAL neuron id folded into
+    ``key`` (padding rows, id -1, have ``thresh`` 1: no events).
+
+    Knuth's product method: multiply uniforms until the product falls to
+    ``thresh = exp(-lam)`` (:func:`drive_threshold`).  Only exactly
+    rounded multiplications and comparisons run on the device, so every
+    platform counts the same events; keying by global id makes the drive
+    independent of how the network is decomposed.
+    """
+    def count(k, t):
+        def body(c):
+            n, prod, k = c
+            k, sub = jax.random.split(k)
+            return n + 1, prod * jax.random.uniform(sub), k
+
+        k, sub = jax.random.split(k)
+        n, _, _ = jax.lax.while_loop(lambda c: c[1] > t, body,
+                                     (jnp.int32(0), jax.random.uniform(sub),
+                                      k))
+        return n
+
+    keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
+        key, jnp.maximum(gid, 0))
+    return jax.vmap(count)(keys, thresh)
+
+
 def _poisson_drive(key, graph: ShardGraph, dt: float, dtype):
     """Background Poisson input accumulated into the excitatory channel."""
-    lam = graph.ext_rate * (dt * 1e-3)
-    events = jax.random.poisson(key, lam, (graph.n_local,))
+    gid = (jnp.arange(graph.n_local, dtype=jnp.int32)
+           if graph.global_id is None else graph.global_id)
+    events = poisson_events(key, drive_threshold(graph.ext_rate, dt), gid)
     return (graph.ext_weight * events).astype(dtype)
 
 

@@ -474,7 +474,8 @@ def make_multihost_step(net: dist.StackedNetwork, mesh: Mesh,
         mesh, groups, cfg, net.max_delay, net.n_local, net.n_mirror,
         net.blocked_meta if backend.needs_blocked else None)
     consts = shard_stacked(
-        dist.stacked_consts(net, needs_blocked=backend.needs_blocked),
+        dist.stacked_consts(net, needs_blocked=backend.needs_blocked,
+                            dt=cfg.engine.dt),
         mesh, local_slice=net.local_slice)
     return smapped, consts
 

@@ -260,14 +260,6 @@ def _require_current(model: NeuronModel, synapse_model: str) -> None:
             "supported (use 'lif' for cond_exp)")
 
 
-def _pad_blocks(n: int, nb: int):
-    """Shared lane-alignment helpers for the elementwise kernels."""
-    pad = (-n) % nb
-    p = lambda a: jnp.pad(a, (0, pad)) if pad else a
-    cut = lambda a: a[:n] if pad else a
-    return p, cut
-
-
 # --------------------------------------------------------------------------
 # LIF: delegates to repro.core.snn - bit-identical to the pre-registry path
 # --------------------------------------------------------------------------
@@ -306,19 +298,17 @@ class LIFModel(NeuronModel):
                                  snn.SynapseModel.COND_EXP):
             raise ValueError(f"unknown synapse model {synapse_model!r}")
         cond = synapse_model == snn.SynapseModel.COND_EXP
-        n = state.v_m.shape[0]
-        p, cut = _pad_blocks(n, nb)
-        f32 = lambda a: p(a).astype(jnp.float32)
+        f32 = lambda a: a.astype(jnp.float32)
         v, se, si, rc, sp = lif_step_kernel(
             f32(state.v_m), f32(state.syn_ex), f32(state.syn_in),
-            p(state.ref_count), p(state.group_id),
+            state.ref_count, state.group_id,
             f32(input_ex), f32(input_in), table.astype(jnp.float32),
             cond=cond, nb=nb, interpret=interpret)
         dtype = state.v_m.dtype
         return snn.NeuronState(
-            v_m=cut(v).astype(dtype), syn_ex=cut(se).astype(dtype),
-            syn_in=cut(si).astype(dtype), ref_count=cut(rc),
-            spike=cut(sp), group_id=state.group_id, extra=state.extra)
+            v_m=v.astype(dtype), syn_ex=se.astype(dtype),
+            syn_in=si.astype(dtype), ref_count=rc,
+            spike=sp, group_id=state.group_id, extra=state.extra)
 
 
 # --------------------------------------------------------------------------
@@ -377,20 +367,18 @@ class IzhikevichModel(NeuronModel):
                     nb: int = 128, interpret: bool = True,
                     key=None, t=None, gid=None):
         _require_current(self, synapse_model)
-        n = state.v_m.shape[0]
-        p, cut = _pad_blocks(n, nb)
-        f32 = lambda a: p(a).astype(jnp.float32)
+        f32 = lambda a: a.astype(jnp.float32)
         v, u, se, si, rc, sp = izh_kernel_mod.izhikevich_step_kernel(
             f32(state.v_m), f32(state.extra["u"]), f32(state.syn_ex),
-            f32(state.syn_in), p(state.ref_count), p(state.group_id),
+            f32(state.syn_in), state.ref_count, state.group_id,
             f32(input_ex), f32(input_in), table.astype(jnp.float32),
             nb=nb, interpret=interpret)
         dtype = state.v_m.dtype
         return snn.NeuronState(
-            v_m=cut(v).astype(dtype), syn_ex=cut(se).astype(dtype),
-            syn_in=cut(si).astype(dtype), ref_count=cut(rc),
-            spike=cut(sp), group_id=state.group_id,
-            extra={"u": cut(u).astype(dtype)})
+            v_m=v.astype(dtype), syn_ex=se.astype(dtype),
+            syn_in=si.astype(dtype), ref_count=rc,
+            spike=sp, group_id=state.group_id,
+            extra={"u": u.astype(dtype)})
 
 
 # --------------------------------------------------------------------------
@@ -448,20 +436,18 @@ class AdExModel(NeuronModel):
                     nb: int = 128, interpret: bool = True,
                     key=None, t=None, gid=None):
         _require_current(self, synapse_model)
-        n = state.v_m.shape[0]
-        p, cut = _pad_blocks(n, nb)
-        f32 = lambda a: p(a).astype(jnp.float32)
+        f32 = lambda a: a.astype(jnp.float32)
         v, w, se, si, rc, sp = adex_kernel_mod.adex_step_kernel(
             f32(state.v_m), f32(state.extra["w_ad"]), f32(state.syn_ex),
-            f32(state.syn_in), p(state.ref_count), p(state.group_id),
+            f32(state.syn_in), state.ref_count, state.group_id,
             f32(input_ex), f32(input_in), table.astype(jnp.float32),
             nb=nb, interpret=interpret)
         dtype = state.v_m.dtype
         return snn.NeuronState(
-            v_m=cut(v).astype(dtype), syn_ex=cut(se).astype(dtype),
-            syn_in=cut(si).astype(dtype), ref_count=cut(rc),
-            spike=cut(sp), group_id=state.group_id,
-            extra={"w_ad": cut(w).astype(dtype)})
+            v_m=v.astype(dtype), syn_ex=se.astype(dtype),
+            syn_in=si.astype(dtype), ref_count=rc,
+            spike=sp, group_id=state.group_id,
+            extra={"w_ad": w.astype(dtype)})
 
 
 # --------------------------------------------------------------------------

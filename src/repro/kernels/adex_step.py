@@ -20,7 +20,7 @@ the upstroke steep (the spike is detected the same step) while every
 intermediate stays finite in fp32.
 
 Same grid/blocking as :mod:`repro.kernels.lif_step`; the table layout is
-owned here so the kernel and the registry's jnp oracle share one gather
+owned here so the kernel and the registry's jnp oracle share one table
 with no import cycle.  Validated bit-exactly against the oracle in
 interpret mode.
 """
@@ -31,7 +31,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from repro.kernels.lanes import elementwise_call, table_getter
 
 __all__ = ["adex_step_kernel", "COL", "NCOL", "_COLS", "EXP_CLAMP"]
 
@@ -91,44 +92,20 @@ def adex_math(v, w, syn_ex, syn_in, rc, iex, iin, get, spike_fn=None):
     return v_new, w_new, se_new, si_new, rc_new, spike_out
 
 
-def _kernel(v_ref, w_ref, se_ref, si_ref, rc_ref, gid_ref, iex_ref, iin_ref,
-            table_ref, v_out, w_out, se_out, si_out, rc_out, spike_out):
-    gid = gid_ref[...][0]
-    tbl = table_ref[...]
-    get = lambda name: jnp.take(tbl[:, COL[name]], gid, axis=0)
-    out = adex_math(v_ref[...][0], w_ref[...][0], se_ref[...][0],
-                    si_ref[...][0], rc_ref[...][0],
-                    iex_ref[...][0], iin_ref[...][0], get)
-    for ref, val in zip((v_out, w_out, se_out, si_out, rc_out, spike_out),
-                        out):
-        ref[...] = val[None]
+def _body(tabs, v, w_ad, syn_ex, syn_in, rc, gid, iex, iin, *,
+          n_groups: int):
+    get = table_getter(tabs[0], gid, n_groups, NCOL, COL)
+    return adex_math(v, w_ad, syn_ex, syn_in, rc, iex, iin, get)
 
 
 @functools.partial(jax.jit, static_argnames=("nb", "interpret"))
 def adex_step_kernel(v, w_ad, syn_ex, syn_in, ref_count, group_id,
                      input_ex, input_in, table, *, nb: int = 512,
                      interpret: bool = True):
-    """All neuron arrays (N,) with N % nb == 0; table (G, NCOL) f32."""
-    n = v.shape[0]
-    assert n % nb == 0, (n, nb)
-    grid = (n // nb,)
-    vec = lambda a: a.reshape(n // nb, nb)
-    blk = pl.BlockSpec((1, nb), lambda i: (i, 0))
-    g = table.shape[0]
-    outs = pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[blk] * 8 + [pl.BlockSpec((g, NCOL), lambda i: (0, 0))],
-        out_specs=[blk] * 6,
-        out_shape=[
-            jax.ShapeDtypeStruct((n // nb, nb), jnp.float32),
-            jax.ShapeDtypeStruct((n // nb, nb), jnp.float32),
-            jax.ShapeDtypeStruct((n // nb, nb), jnp.float32),
-            jax.ShapeDtypeStruct((n // nb, nb), jnp.float32),
-            jax.ShapeDtypeStruct((n // nb, nb), jnp.int32),
-            jax.ShapeDtypeStruct((n // nb, nb), jnp.bool_),
-        ],
-        interpret=interpret,
-    )(vec(v), vec(w_ad), vec(syn_ex), vec(syn_in), vec(ref_count),
-      vec(group_id), vec(input_ex), vec(input_in), table)
-    return tuple(o.reshape(n) for o in outs)
+    """All neuron arrays (N,); table (G, NCOL) f32."""
+    f32, i32 = jnp.float32, jnp.int32
+    return elementwise_call(
+        functools.partial(_body, n_groups=table.shape[0]),
+        (v, w_ad, syn_ex, syn_in, ref_count, group_id, input_ex, input_in),
+        (f32, f32, f32, f32, i32, jnp.bool_), block=nb,
+        tables=(table.astype(f32).reshape(-1),), interpret=interpret)

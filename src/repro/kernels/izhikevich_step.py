@@ -13,11 +13,11 @@ convention, same as the LIF path).  ``u`` rides the model-generic
 ``NeuronState.extra["u"]`` slot (DESIGN.md §12).
 
 Pure elementwise over neurons (VPU work), same grid/blocking as
-:mod:`repro.kernels.lif_step`: 1-D over ``NB``-wide neuron blocks, the tiny
-per-group parameter table resident in VMEM for every cell.  The parameter
+:mod:`repro.kernels.lif_step`: lane-dense neuron blocks, the tiny
+per-group parameter table resident in SMEM for every cell.  The parameter
 table layout (COL / NCOL below) is owned HERE so the registry's jnp oracle
 (:class:`repro.core.neuron_models.IzhikevichModel`) and the kernel share
-one gather without an import cycle.
+one table without an import cycle.
 
 Validated bit-exactly against the jnp oracle in interpret mode (identical
 op order, DESIGN.md §12 contract).
@@ -29,7 +29,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from repro.kernels.lanes import elementwise_call, table_getter
 
 __all__ = ["izhikevich_step_kernel", "COL", "NCOL", "_COLS"]
 
@@ -84,44 +85,20 @@ def izhikevich_math(v, u, syn_ex, syn_in, rc, iex, iin, get, spike_fn=None):
     return v_new, u_new, se_new, si_new, rc_new, spike_out
 
 
-def _kernel(v_ref, u_ref, se_ref, si_ref, rc_ref, gid_ref, iex_ref, iin_ref,
-            table_ref, v_out, u_out, se_out, si_out, rc_out, spike_out):
-    gid = gid_ref[...][0]
-    tbl = table_ref[...]
-    get = lambda name: jnp.take(tbl[:, COL[name]], gid, axis=0)
-    out = izhikevich_math(v_ref[...][0], u_ref[...][0], se_ref[...][0],
-                          si_ref[...][0], rc_ref[...][0],
-                          iex_ref[...][0], iin_ref[...][0], get)
-    for ref, val in zip((v_out, u_out, se_out, si_out, rc_out, spike_out),
-                        out):
-        ref[...] = val[None]
+def _body(tabs, v, u, syn_ex, syn_in, rc, gid, iex, iin, *,
+          n_groups: int):
+    get = table_getter(tabs[0], gid, n_groups, NCOL, COL)
+    return izhikevich_math(v, u, syn_ex, syn_in, rc, iex, iin, get)
 
 
 @functools.partial(jax.jit, static_argnames=("nb", "interpret"))
 def izhikevich_step_kernel(v, u, syn_ex, syn_in, ref_count, group_id,
                            input_ex, input_in, table, *, nb: int = 512,
                            interpret: bool = True):
-    """All neuron arrays (N,) with N % nb == 0; table (G, NCOL) f32."""
-    n = v.shape[0]
-    assert n % nb == 0, (n, nb)
-    grid = (n // nb,)
-    vec = lambda a: a.reshape(n // nb, nb)
-    blk = pl.BlockSpec((1, nb), lambda i: (i, 0))
-    g = table.shape[0]
-    outs = pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[blk] * 8 + [pl.BlockSpec((g, NCOL), lambda i: (0, 0))],
-        out_specs=[blk] * 6,
-        out_shape=[
-            jax.ShapeDtypeStruct((n // nb, nb), jnp.float32),
-            jax.ShapeDtypeStruct((n // nb, nb), jnp.float32),
-            jax.ShapeDtypeStruct((n // nb, nb), jnp.float32),
-            jax.ShapeDtypeStruct((n // nb, nb), jnp.float32),
-            jax.ShapeDtypeStruct((n // nb, nb), jnp.int32),
-            jax.ShapeDtypeStruct((n // nb, nb), jnp.bool_),
-        ],
-        interpret=interpret,
-    )(vec(v), vec(u), vec(syn_ex), vec(syn_in), vec(ref_count),
-      vec(group_id), vec(input_ex), vec(input_in), table)
-    return tuple(o.reshape(n) for o in outs)
+    """All neuron arrays (N,); table (G, NCOL) f32."""
+    f32, i32 = jnp.float32, jnp.int32
+    return elementwise_call(
+        functools.partial(_body, n_groups=table.shape[0]),
+        (v, u, syn_ex, syn_in, ref_count, group_id, input_ex, input_in),
+        (f32, f32, f32, f32, i32, jnp.bool_), block=nb,
+        tables=(table.astype(f32).reshape(-1),), interpret=interpret)

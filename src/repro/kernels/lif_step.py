@@ -1,15 +1,15 @@
 """Pallas TPU kernel: fused LIF neuron update (exact-integration propagators).
 
 Pure elementwise over neurons (VPU work): one pass reads the neuron state
-block plus the per-group propagator table (tiny, resident in VMEM for every
+block plus the per-group propagator table (tiny, resident in SMEM for every
 grid cell) and writes the propagated state + spike bits.  Fusing the
 propagate / threshold / reset / refractory chain into one kernel removes
 five HBM round-trips of the unfused XLA elementwise chain - this mirrors the
 paper's "neural dynamics" stage (Fig. 6e) on TPU.
 
-Grid: 1-D over neuron blocks of ``NB`` (multiple of 128 for lane alignment).
-Validated against :func:`repro.core.snn.lif_step` (the jnp oracle) in
-interpret mode.
+Grid: 1-D over lane-dense ``(rb, 128)`` blocks of about ``nb`` neurons
+(:mod:`repro.kernels.lanes`).  Validated against
+:func:`repro.core.snn.lif_step` (the jnp oracle) in interpret mode.
 """
 
 from __future__ import annotations
@@ -18,33 +18,24 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.core.snn import COL, NCOL
+from repro.kernels.lanes import elementwise_call, table_getter
 
 __all__ = ["lif_step_kernel", "DEFAULT_NB"]
 
 DEFAULT_NB = 512
 
 
-def _kernel(v_ref, se_ref, si_ref, rc_ref, gid_ref, iex_ref, iin_ref,
-            table_ref, v_out, se_out, si_out, rc_out, spike_out,
-            *, cond: bool):
-    gid = gid_ref[...][0]
-    tbl = table_ref[...]            # (G, NCOL)
-    get = lambda name: jnp.take(tbl[:, COL[name]], gid, axis=0)
-
-    v = v_ref[...][0]
-    syn_ex = se_ref[...][0]
-    syn_in = si_ref[...][0]
-    rc = rc_ref[...][0]
-
+def _body(tabs, v, syn_ex, syn_in, rc, gid, iex, iin, *, cond: bool,
+          n_groups: int):
+    get = table_getter(tabs[0], gid, n_groups, NCOL, COL)
     p_vv, p_ee, p_ii = get("p_vv"), get("p_ee"), get("p_ii")
     v_th, v_reset = get("v_th"), get("v_reset")
     ref_steps = get("ref_steps").astype(jnp.int32)
 
-    se_new = syn_ex * p_ee + iex_ref[...][0]
-    si_new = syn_in * p_ii + iin_ref[...][0]
+    se_new = syn_ex * p_ee + iex
+    si_new = syn_in * p_ii + iin
 
     if cond:
         i_cond = syn_ex * (get("e_ex") - v) - syn_in * (v - get("e_in"))
@@ -59,39 +50,18 @@ def _kernel(v_ref, se_ref, si_ref, rc_ref, gid_ref, iex_ref, iin_ref,
     v_new = jnp.where(spike, v_reset, v_new)
     rc_new = jnp.where(spike, ref_steps,
                        jnp.maximum(rc - 1, 0).astype(jnp.int32))
-
-    v_out[...] = v_new[None]
-    se_out[...] = se_new[None]
-    si_out[...] = si_new[None]
-    rc_out[...] = rc_new[None]
-    spike_out[...] = spike[None]
+    return v_new, se_new, si_new, rc_new, spike
 
 
 @functools.partial(jax.jit, static_argnames=("cond", "nb", "interpret"))
 def lif_step_kernel(v, syn_ex, syn_in, ref_count, group_id, input_ex,
                     input_in, table, *, cond: bool = False,
                     nb: int = DEFAULT_NB, interpret: bool = True):
-    """All neuron arrays (N,) with N % nb == 0; table (G, NCOL) f32."""
-    n = v.shape[0]
-    assert n % nb == 0, (n, nb)
-    grid = (n // nb,)
-    vec = lambda a: a.reshape(n // nb, nb)
-    blk = pl.BlockSpec((1, nb), lambda i: (i, 0))
-    g = table.shape[0]
-    outs = pl.pallas_call(
-        functools.partial(_kernel, cond=cond),
-        grid=grid,
-        in_specs=[blk] * 7 + [pl.BlockSpec((g, NCOL), lambda i: (0, 0))],
-        out_specs=[blk] * 5,
-        out_shape=[
-            jax.ShapeDtypeStruct((n // nb, nb), jnp.float32),
-            jax.ShapeDtypeStruct((n // nb, nb), jnp.float32),
-            jax.ShapeDtypeStruct((n // nb, nb), jnp.float32),
-            jax.ShapeDtypeStruct((n // nb, nb), jnp.int32),
-            jax.ShapeDtypeStruct((n // nb, nb), jnp.bool_),
-        ],
-        interpret=interpret,
-    )(vec(v), vec(syn_ex), vec(syn_in), vec(ref_count), vec(group_id),
-      vec(input_ex), vec(input_in), table)
-    v2, se2, si2, rc2, sp = (o.reshape(n) for o in outs)
-    return v2, se2, si2, rc2, sp
+    """All neuron arrays (N,); table (G, NCOL) f32.  Returns
+    ``(v, syn_ex, syn_in, ref_count, spike)``."""
+    f32, i32 = jnp.float32, jnp.int32
+    return elementwise_call(
+        functools.partial(_body, cond=cond, n_groups=table.shape[0]),
+        (v, syn_ex, syn_in, ref_count, group_id, input_ex, input_in),
+        (f32, f32, f32, i32, jnp.bool_), block=nb,
+        tables=(table.astype(f32).reshape(-1),), interpret=interpret)

@@ -5,21 +5,22 @@ The nonlinear per-edge update of the verification case (§IV.A):
     w -= pre_arrived * lam*alpha * w * K_post[post]
     w += post_spiked * lam * w0^(1-mu) * w^mu * K_pre[pre]
 
-Race-freedom is inherited from the indegree layout: each edge block belongs
-to one post-block owner, and the only writes are to the block's own weight
-rows.  Trace vectors (K_pre over mirrors, K_post over owned posts) are small
-per shard and live fully in VMEM; the two per-edge gathers are flat VMEM
-gathers.  The power ``w^mu`` runs as exp(mu*log(w)) on the VPU
-(transcendental), masked on padding edges.
+Race-freedom is inherited from the indegree layout: each edge belongs to
+one post-block owner, and the only write is to the edge's own weight.  The
+three per-edge lookups (``K_pre[pre]``, ``K_post[post]``, ``spike[post]``)
+are XLA gathers ahead of the kernel - the TPU compiler lowers no general
+gather inside a kernel - so the kernel itself is a pure elementwise pass
+over lane-dense edge blocks (:mod:`repro.kernels.lanes`).  The power
+``w^mu`` runs as exp(mu*log(w)) on the VPU (transcendental), masked on
+padding edges.
 
 Two edge layouts are served (DESIGN.md §9):
 
-* flat owner-sorted ``(E,)`` arrays with absolute ``post_idx`` - the
-  original form, blocked internally into ``eb``-wide grid cells;
-* the post-block ELL layout ``(NB, EB)`` with **block-relative** post rows
-  (``pb`` given): grid cell ``i`` owns post rows ``[i*pb, (i+1)*pb)``, so
-  the absolute post index is reconstructed as ``i*pb + post_rel`` inside
-  the kernel - the blocked-resident hot path consumes the sweep kernel's
+* flat owner-sorted ``(E,)`` arrays with absolute ``post_idx``;
+* the post-block ELL layout ``(NB, EB)`` flattened to slot order, with
+  **block-relative** post rows (``pb`` given): slot row ``i`` belongs to
+  post rows ``[i*pb, (i+1)*pb)``, so the absolute post index is
+  ``i*pb + post_rel`` - the blocked-resident hot path consumes the sweep's
   arrivals and weights without any layout conversion.
 
 Validated against :func:`repro.core.stdp.stdp_edge_update` in interpret
@@ -32,33 +33,39 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from repro.kernels.lanes import elementwise_call
 
 __all__ = ["stdp_update_kernel", "stdp_update_worklist", "DEFAULT_EB"]
 
 DEFAULT_EB = 2048
+#: edges per grid cell of the elementwise pass
+_BLOCK = 64 * 1024
 
 
-def _kernel(w_ref, pre_ref, post_ref, plast_ref, arrived_ref, spike_ref,
-            kpre_ref, kpost_ref, w_out, *, lam, alpha, mu, w0, wmin, wmax,
-            pb: int):
-    w = w_ref[...][0]
-    pre = pre_ref[...][0]
-    post = post_ref[...][0]
-    plastic = plast_ref[...][0]
-    arrived = arrived_ref[...][0]
-    if pb:  # ELL layout: post rows are block-relative, offset by the owner
-        post = post + pl.program_id(0) * pb
-
-    k_post = jnp.take(kpost_ref[...].reshape(-1), post, axis=0)
-    k_pre = jnp.take(kpre_ref[...].reshape(-1), pre, axis=0)
-    post_sp = jnp.take(spike_ref[...].reshape(-1), post, axis=0)
-
+def _body(_, w, plastic, arrived, k_pre, k_post, post_sp, *, lam, alpha,
+          mu, w0, wmin, wmax):
     w1 = w - arrived * (lam * alpha) * w * k_post
     w_safe = jnp.maximum(w1, 1e-12)
     pot = lam * (w0 ** (1.0 - mu)) * jnp.exp(mu * jnp.log(w_safe)) * k_pre
     w2 = jnp.clip(w1 + post_sp * pot, wmin, wmax)
-    w_out[...] = jnp.where(plastic, w2, w)[None]
+    return (jnp.where(plastic, w2, w),)
+
+
+def _update(weights, pre, post, plastic, arrived, post_spike, k_pre,
+            k_post, params, interpret):
+    """Gather the per-edge traces/spikes (XLA), then the elementwise
+    kernel.  Out-of-range indices (worklist padding) clamp; their rows are
+    dropped by the caller."""
+    lam, alpha, mu, w0, wmin, wmax = params
+    take = lambda a, i: jnp.take(a, i, axis=0, mode="clip")
+    (new_w,) = elementwise_call(
+        functools.partial(_body, lam=lam, alpha=alpha, mu=mu, w0=w0,
+                          wmin=wmin, wmax=wmax),
+        (weights, plastic, arrived, take(k_pre, pre), take(k_post, post),
+         take(post_spike, post)),
+        (jnp.float32,), block=_BLOCK, interpret=interpret)
+    return new_w
 
 
 @functools.partial(jax.jit, static_argnames=("eb", "interpret", "params",
@@ -67,70 +74,22 @@ def stdp_update_kernel(weights, pre_idx, post_idx, plastic, arrived,
                        post_spike, k_pre, k_post, *, params,
                        eb: int = DEFAULT_EB, interpret: bool = True,
                        pb: int = 0):
-    """weights/pre/post/plastic/arrived: (E,) owner-sorted (E % eb == 0);
+    """weights/pre/post/plastic/arrived: (E,) owner-sorted;
     post_spike (n_local,) f32; traces k_pre (M,), k_post (n_local,).
     ``params`` is a hashable tuple (lam, alpha, mu, w0, wmin, wmax).
 
     With ``pb > 0`` the edge arrays are the blocked ELL layout flattened to
     ``(NB*EB,)`` slot order: ``post_idx`` holds block-RELATIVE rows and
-    ``eb`` must be the layout's per-block edge count, so grid cell ``i``
-    covers exactly post block ``i``.  The returned weights stay in the same
-    slot order.
+    ``eb`` must be the layout's per-block edge count.  The returned weights
+    stay in the same slot order.
     """
-    lam, alpha, mu, w0, wmin, wmax = params
-    e = weights.shape[0]
-    assert e % eb == 0, (e, eb)
-    nb = e // eb
-    vec = lambda a: a.reshape(nb, eb)
-    blk = pl.BlockSpec((1, eb), lambda i: (i, 0))
-    m = k_pre.shape[0]
-    nl = k_post.shape[0]
-    full = lambda shape: pl.BlockSpec(shape, lambda i: tuple(
-        0 for _ in shape))
-    out = pl.pallas_call(
-        functools.partial(_kernel, lam=lam, alpha=alpha, mu=mu, w0=w0,
-                          wmin=wmin, wmax=wmax, pb=pb),
-        grid=(nb,),
-        in_specs=[blk, blk, blk, blk, blk,
-                  full((nl,)), full((m,)), full((nl,))],
-        out_specs=blk,
-        out_shape=jax.ShapeDtypeStruct((nb, eb), jnp.float32),
-        interpret=interpret,
-    )(vec(weights), vec(pre_idx), vec(post_idx), vec(plastic),
-      vec(arrived), post_spike, k_pre, k_post)
-    return out.reshape(e)
-
-
-# --------------------------------------------------------------------------
-# worklist-aware grid (activity-gated backend, DESIGN.md §13)
-# --------------------------------------------------------------------------
-
-def _wl_kernel(wl_ref, w_ref, pre_ref, post_ref, plast_ref, arrived_ref,
-               spike_ref, kpre_ref, kpost_ref, w_out, *, lam, alpha, mu, w0,
-               wmin, wmax, pb: int):
-    """Same pl-STDP update as :func:`_kernel` in ELL mode, but the owning
-    post block is read from the worklist instead of ``program_id`` - grid
-    cell ``i`` covers post block ``worklist[i]``, so the grid dispatches
-    only the gate's ACTIVE blocks (compacted inputs)."""
-    w = w_ref[...][0]
-    pre = pre_ref[...][0]
-    post = post_ref[...][0]
-    plastic = plast_ref[...][0]
-    arrived = arrived_ref[...][0]
-    # absolute post rows of the owning block; padding worklist slots carry
-    # an out-of-range sentinel whose gathers clamp (jnp.take clips under
-    # jit) and whose output row the caller drops at the scatter
-    post = post + wl_ref[0] * pb
-
-    k_post = jnp.take(kpost_ref[...].reshape(-1), post, axis=0)
-    k_pre = jnp.take(kpre_ref[...].reshape(-1), pre, axis=0)
-    post_sp = jnp.take(spike_ref[...].reshape(-1), post, axis=0)
-
-    w1 = w - arrived * (lam * alpha) * w * k_post
-    w_safe = jnp.maximum(w1, 1e-12)
-    pot = lam * (w0 ** (1.0 - mu)) * jnp.exp(mu * jnp.log(w_safe)) * k_pre
-    w2 = jnp.clip(w1 + post_sp * pot, wmin, wmax)
-    w_out[...] = jnp.where(plastic, w2, w)[None]
+    if pb:
+        e = weights.shape[0]
+        assert e % eb == 0, (e, eb)
+        blk = jnp.arange(e // eb, dtype=jnp.int32)[:, None] * pb
+        post_idx = (blk + post_idx.reshape(e // eb, eb)).reshape(e)
+    return _update(weights, pre_idx, post_idx, plastic, arrived,
+                   post_spike, k_pre, k_post, params, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "params", "pb"))
@@ -147,21 +106,8 @@ def stdp_update_worklist(weights, pre_idx, post_rel, plastic, arrived,
     updated (G, EB) weights in the same compacted order.
     """
     g, eb = weights.shape
-    blk = pl.BlockSpec((1, eb), lambda i: (i, 0))
-    m = k_pre.shape[0]
-    nl = k_post.shape[0]
-    full = lambda shape: pl.BlockSpec(shape, lambda i: tuple(
-        0 for _ in shape))
-    lam, alpha, mu, w0, wmin, wmax = params
-    return pl.pallas_call(
-        functools.partial(_wl_kernel, lam=lam, alpha=alpha, mu=mu, w0=w0,
-                          wmin=wmin, wmax=wmax, pb=pb),
-        grid=(g,),
-        in_specs=[pl.BlockSpec((1,), lambda i: (i,)),
-                  blk, blk, blk, blk, blk,
-                  full((nl,)), full((m,)), full((nl,))],
-        out_specs=blk,
-        out_shape=jax.ShapeDtypeStruct((g, eb), jnp.float32),
-        interpret=interpret,
-    )(worklist, weights, pre_idx, post_rel, plastic, arrived,
-      post_spike, k_pre, k_post)
+    post = (worklist[:, None] * pb + post_rel).reshape(-1)
+    flat = lambda a: a.reshape(-1)
+    return _update(flat(weights), flat(pre_idx), post, flat(plastic),
+                   flat(arrived), post_spike, k_pre, k_post, params,
+                   interpret).reshape(g, eb)

@@ -79,7 +79,9 @@ def state_and_consts_sds(dims, mesh, axes, *, compact: bool = False):
         delay=sds((e,), small_t), channel=sds((e,), small_t),
         plastic=sds((e,), jnp.bool_), weight_init=sds((e,), f32),
         group_id=sds((nl,), i32), ext_rate=sds((nl,), f32),
-        ext_weight=sds((nl,), f32), mirror_src_idx=sds((nm,), idx_t),
+        ext_weight=sds((nl,), f32), ext_thresh=sds((nl,), f32),
+        global_id=sds((nl,), i32),
+        mirror_src_idx=sds((nm,), idx_t),
         boundary_slots=sds((b,), idx_t), mirror_is_intra=sds((nm,), jnp.bool_),
         mirror_row_gather=sds((nm,), i32),
         mirror_remote_gather=sds((nm,), i32), mirror_src_flat=sds((nm,), i32),

@@ -17,24 +17,34 @@ For the SNN engine the same axes carry the paper's decomposition:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_test_mesh", "make_snn_host_mesh",
-           "POD_SHAPE", "SINGLE_POD_SHAPE"]
+           "auto_mesh", "POD_SHAPE", "SINGLE_POD_SHAPE"]
 
 SINGLE_POD_SHAPE = (16, 16)              # 256 chips (one v5e pod)
 POD_SHAPE = (2, 16, 16)                  # 2 pods = 512 chips
 
 
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``: the sharding-
+    propagation mode the LM substrate is written for (``shard_act``
+    constraints, GSPMD propagation).  JAX's own default is ``Explicit``
+    axes, under which every reshape or gather of a sharded array must name
+    its output sharding."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = POD_SHAPE if multi_pod else SINGLE_POD_SHAPE
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small host-device mesh for CPU integration tests (requires
     XLA_FLAGS=--xla_force_host_platform_device_count>=prod(shape))."""
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_snn_host_mesh(n_rows: int, row_width: int):

@@ -154,9 +154,15 @@ def build_parser() -> argparse.ArgumentParser:
 # --------------------------------------------------------------------------
 
 def _child_env(args) -> dict:
+    """Worker environment.  The local gang is a CPU rehearsal of a
+    multi-host run (gloo collectives between forced host devices), so
+    every worker is pinned to the CPU: an accelerator takes one process
+    at a time, and N workers (or the launcher itself) would contend for
+    it."""
     src = os.path.join(os.path.dirname(__file__), "..", "..")
     return dict(
         os.environ,
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count="
                   f"{args.devices_per_process}",
         PYTHONPATH=os.pathsep.join(

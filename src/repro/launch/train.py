@@ -22,10 +22,12 @@ from repro import configs
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs.base import TrainConfig
 from repro.data.pipeline import TokenPipeline
+from repro.launch.mesh import auto_mesh
 from repro.models.model import build_model
 from repro.runtime.fault import HeartbeatMonitor
 from repro.train.loop import make_train_step
 from repro.train.optimizer import init_opt_state
+from repro.utils.compile_cache import use_compile_cache
 
 
 def parse_mesh(s: str | None):
@@ -34,7 +36,7 @@ def parse_mesh(s: str | None):
     dims = tuple(int(x) for x in s.split("x"))
     axes = {1: ("data",), 2: ("data", "model"),
             3: ("pod", "data", "model")}[len(dims)]
-    return jax.make_mesh(dims, axes)
+    return auto_mesh(dims, axes)
 
 
 def main() -> None:
@@ -53,6 +55,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--save-every", type=int, default=10)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(
         args.arch)

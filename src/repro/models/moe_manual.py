@@ -38,7 +38,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import mlp_apply
-from repro.utils.jax_compat import shard_map
 from repro.sharding import rules
 
 __all__ = ["expert_axes_for", "moe_apply_manual", "expert_param_spec"]
@@ -221,10 +220,10 @@ def moe_apply_manual(p, cfg_moe, mlp_kind: str, x, compute_dtype,
         return y, aux_loss, drop
 
     spec_e = expert_param_spec(mesh, e.n_experts)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(bspec, P(), spec_e, spec_e, spec_e),
-        out_specs=(bspec, P(), P()))
+        out_specs=(bspec, P(), P()), check_vma=False)
     y, aux_loss, drop = fn(x, p["router"]["w"], p["wi_gate"], p["wi_up"],
                            p["wo"])
 

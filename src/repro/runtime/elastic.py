@@ -123,7 +123,7 @@ def shrink_remap_state(spec, seed: int, host: dict, *, step: int,
         for s in range(lo, hi)]
 
     # fresh state on the NEW topology: regenerated weights/layout, fresh
-    # per-shard key split for the new shard count, model aux structure
+    # key, model aux structure
     fresh = dist.init_stacked_state(new_net, list(groups), seed=seed,
                                     sweep=sweep, neuron_model=neuron_model)
     fields = {}
@@ -166,9 +166,8 @@ def shrink_remap_state(spec, seed: int, host: dict, *, step: int,
         fields["ring"][i][:, :mg.size] = ring_g[:, mg]
 
     fields["t"][:] = step
-    # per-shard key streams are shard-count-specific: re-derive the global
-    # split for the NEW count and advance it by the steps already run (the
-    # exact stream an uninterrupted run on this topology would hold)
+    # advance the fresh key by the steps already run (the exact stream an
+    # uninterrupted run holds; it is the same for every shard count)
     fields["key"] = np.array(dist.advance_key_data(
         jnp.asarray(fields["key"]), step))
 

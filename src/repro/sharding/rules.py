@@ -31,7 +31,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["use_mesh", "shard_act", "param_specs", "cache_specs",
            "batch_spec", "act_spec", "named_sharding", "current_mesh"]
@@ -110,6 +110,13 @@ class MeshCtx:
 
 @contextlib.contextmanager
 def use_mesh(mesh: Mesh):
+    """Activate ``mesh`` for the logical-axis rules.  The rules constrain
+    activations and let sharding propagation do the rest, so every mesh
+    axis must be ``AxisType.Auto`` (``repro.launch.mesh.auto_mesh``)."""
+    if any(t != AxisType.Auto for t in mesh.axis_types):
+        raise ValueError(
+            f"use_mesh needs AxisType.Auto mesh axes, got {mesh.axis_types}"
+            " - build the mesh with repro.launch.mesh.auto_mesh")
     token = _CTX.set(MeshCtx(mesh))
     try:
         with mesh:

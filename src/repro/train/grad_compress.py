@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.utils.jax_compat import shard_map
 
 __all__ = ["quantize", "dequantize", "ef_compress_step",
            "make_cross_pod_reduce", "init_error_state"]
@@ -91,8 +90,9 @@ def make_cross_pod_reduce(mesh: Mesh, *, compress: bool = True):
         # grads are already sharded over (data, model); shard_map manual
         # only over "pod", auto over the rest.
         spec = P()  # per-pod replica view of the (data,model)-sharded leaf
-        f = shard_map(body, mesh=mesh, in_specs=(spec, spec),
-                      out_specs=(spec, spec), axis_names={"pod"})
+        f = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                          out_specs=(spec, spec), axis_names={"pod"},
+                          check_vma=False)
         return f(g, err)
 
     def reduce_tree(grads, err_tree):

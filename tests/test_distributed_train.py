@@ -26,6 +26,7 @@ CODE = textwrap.dedent("""
     from repro.checkpoint.manager import CheckpointManager
     from repro.configs.base import TrainConfig
     from repro.data.pipeline import TokenPipeline
+    from repro.launch.mesh import make_test_mesh
     from repro.models.model import build_model
     from repro.sharding import rules
     from repro.train.loop import make_train_step
@@ -39,7 +40,7 @@ CODE = textwrap.dedent("""
                          global_batch=4, seed=5)
 
     # ---- phase 1: multi-pod mesh (2,2,2) -------------------------------
-    mesh1 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh1 = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
     with rules.use_mesh(mesh1):
         params = m.init(jax.random.key(0))
         p_sh = rules.param_specs(mesh1, jax.eval_shape(lambda: params))
@@ -63,7 +64,7 @@ CODE = textwrap.dedent("""
     mgr.save(6, (params, opt), metadata={"step": 6})
 
     # ---- phase 2: elastic restart on a SMALLER mesh (2,2) --------------
-    mesh2 = jax.make_mesh((2, 2), ("data", "model"))
+    mesh2 = make_test_mesh((2, 2), ("data", "model"))
     with rules.use_mesh(mesh2):
         p2_sh = rules.param_specs(mesh2, jax.eval_shape(lambda: params))
         o2_sh = rules.param_specs(mesh2, jax.eval_shape(lambda: opt))

@@ -22,9 +22,10 @@ CODE = textwrap.dedent("""
     import json
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P, NamedSharding
+    from repro.launch.mesh import make_test_mesh
     from repro.utils.hlo_analysis import analyze_hlo
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_test_mesh((2, 4), ("data", "model"))
     L, D, B = 5, 64, 8
 
     def f(params, x):

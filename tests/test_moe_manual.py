@@ -19,11 +19,12 @@ CODE = textwrap.dedent("""
     import numpy as np
     import jax, jax.numpy as jnp
     from repro.configs.base import MoEConfig
+    from repro.launch.mesh import make_test_mesh
     from repro.models import moe as moe_mod
     from repro.models.moe_manual import expert_axes_for, expert_param_spec
     from repro.sharding import rules
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_test_mesh((2, 4), ("data", "model"))
     res = {}
     # E=8 divides data*model=8 -> full 2-axis expert residency
     e = MoEConfig(n_experts=8, top_k=2, expert_ff=16, capacity_factor=16.0)

@@ -26,9 +26,9 @@ def test_param_rules_cover_all_archs():
     from repro import configs
     from repro.models.model import build_model
     from repro.sharding import rules
-    from repro.utils.jax_compat import abstract_mesh
+    from jax.sharding import AbstractMesh
 
-    mesh = abstract_mesh((4, 4), ("data", "model"))
+    mesh = AbstractMesh((4, 4), ("data", "model"))
     for arch in configs.ARCH_NAMES:
         cfg = configs.get_smoke(arch)
         m = build_model(cfg)
@@ -54,9 +54,9 @@ def test_cache_specs_head_vs_seq_fallback():
     import jax
     import jax.numpy as jnp
     from repro.sharding import rules
-    from repro.utils.jax_compat import abstract_mesh
+    from jax.sharding import AbstractMesh
 
-    mesh = abstract_mesh((2, 8), ("data", "model"))
+    mesh = AbstractMesh((2, 8), ("data", "model"))
     cache = {"period": {"k": jax.ShapeDtypeStruct((4, 16, 64, 2, 8),
                                                   jnp.bfloat16),
                         "v": jax.ShapeDtypeStruct((4, 16, 64, 2, 8),
@@ -77,9 +77,10 @@ DRYRUN_SMALL = textwrap.dedent("""
     from repro import configs
     from repro.configs.base import ShapeConfig
     from repro.launch import dryrun
+    from repro.launch.mesh import make_test_mesh
     from repro.sharding import rules
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
     results = {}
     cells = [
         ("qwen2.5-3b", ShapeConfig("train", "train", 64, 8, 2)),
@@ -121,9 +122,9 @@ def test_input_specs_match_model_inputs():
     from repro import configs
     from repro.configs.shapes import SHAPES
     from repro.launch.dryrun import input_specs
-    from repro.utils.jax_compat import abstract_mesh
+    from jax.sharding import AbstractMesh
 
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     for arch in configs.ARCH_NAMES:
         cfg = configs.get(arch)
         sp = input_specs(cfg, SHAPES["train_4k"], mesh)

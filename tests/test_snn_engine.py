@@ -337,3 +337,39 @@ def test_conductance_synapse_model():
     final, spikes, _ = run_spec(spec, 500, cfg)
     v = np.asarray(final.neurons.v_m)
     assert (v <= 0.1).all() and np.isfinite(v).all()
+
+
+@pytest.mark.parametrize("rate_hz", [0.0, 15128.0, 46875.0])
+def test_poisson_drive_counts(rate_hz):
+    """The external drive draws exact Poisson counts: the empirical pmf
+    of 200k draws matches exp(-lam) lam^k / k! within 4 sigma."""
+    n = 200_000
+    thresh = engine.drive_threshold(np.full(n, rate_hz, np.float32), 0.1)
+    ev = np.asarray(jax.jit(engine.poisson_events)(
+        jax.random.key(3), jnp.asarray(thresh),
+        jnp.arange(n, dtype=jnp.int32)))
+    lam = rate_hz * 1e-4
+    k = np.arange(12)
+    pmf = np.exp(-lam) * lam ** k / np.cumprod(np.maximum(k, 1))
+    emp = np.bincount(ev, minlength=12)[:12] / n
+    assert np.all(np.abs(emp - pmf) <= 4 * np.sqrt(pmf * (1 - pmf) / n)
+                  + 1e-12)
+
+
+def test_poisson_drive_is_platform_exact_and_keyed_by_global_id():
+    """No transcendental runs on the device (TPU and CPU log/exp differ in
+    the last bits; only multiplications and comparisons decide a count),
+    and a neuron's events depend on its GLOBAL id, not its row."""
+    thresh = jnp.asarray(engine.drive_threshold(
+        np.full(64, 18400.0, np.float32), 0.1))
+    gid = jnp.arange(64, dtype=jnp.int32)
+    text = str(jax.make_jaxpr(engine.poisson_events)(jax.random.key(0),
+                                                      thresh, gid))
+    prims = {w.split("[")[0] for w in text.split()}
+    assert "while" in prims and "mul" in prims
+    assert not prims & {"log", "exp", "log1p", "expm1", "pow"}
+    perm = jnp.asarray(np.random.default_rng(0).permutation(64))
+    a = engine.poisson_events(jax.random.key(5), thresh, gid)
+    b = engine.poisson_events(jax.random.key(5), thresh[perm], gid[perm])
+    np.testing.assert_array_equal(np.asarray(a)[np.asarray(perm)],
+                                  np.asarray(b))

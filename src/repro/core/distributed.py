@@ -66,7 +66,7 @@ from repro.core.builder import NetworkSpec, build_shards
 from repro.core.decomposition import (Decomposition, apportion_devices,
                                       multisection_divide)
 from repro.core.engine import (DRIVE_SALT, EngineConfig, ShardGraph,
-                               drive_threshold, poisson_events)
+                               drive_threshold, phase, poisson_events)
 from repro.core.layout import BlockedGraph, DEFAULT_PB
 
 __all__ = ["mesh_decompose", "StackedNetwork", "prepare_stacked",
@@ -965,6 +965,12 @@ def _build_step(mesh: Mesh, groups, cfg: DistributedConfig, max_delay: int,
         The hot path (sweep, neuron update, STDP) is the SAME backend code
         the single-shard engine dispatches to; only the spike exchange and
         the overlap schedule around it are distributed-specific.
+
+        The work runs in the ``engine.PHASES`` scopes, the names the chip
+        benchmark reads: ``exchange`` (issue and finish of the spike
+        exchange), ``sweep`` (the synaptic sweep with its ring write),
+        ``neuron`` (the external drive and the neuron update) and ``stdp``
+        (as in ``engine.engine_step``).
         """
         # edge/index arrays may arrive in compact dtypes (u16 indices, i8
         # delays - §Perf: the static edge arrays dominate sweep traffic);
@@ -997,79 +1003,85 @@ def _build_step(mesh: Mesh, groups, cfg: DistributedConfig, max_delay: int,
         # first - and their results consumed only below, so under
         # cfg.overlap the delay>=2 sweep (old ring slots only) never waits
         # on the wire (tests/test_multihost.py pins the independence)
-        payloads, overflow = _exchange_issue(state.prev_bits, g, cfg, wire,
-                                             remote_wire)
-        mirror_prev = _exchange_finish(payloads, g, cfg, wire, remote_wire,
-                                       n_local, dtype)
+        with phase("exchange"):
+            payloads, overflow = _exchange_issue(state.prev_bits, g, cfg,
+                                                 wire, remote_wire)
+            mirror_prev = _exchange_finish(payloads, g, cfg, wire,
+                                           remote_wire, n_local, dtype)
 
         # ---- (2) synaptic sweep ------------------------------------------
-        if cfg.overlap:
-            # backend splits delays >= 2 (old ring, independent of the
-            # collective) from delay == 1 (the fresh exchange) when it can;
-            # otherwise it degrades to write-then-sweep
-            (input_ex, input_in, arrived, ring,
-             gate_ovf) = backend.sweep_overlap_with_stats(
-                layout, w_native, state.ring, t, mirror_prev)
-        else:
-            # naive schedule: write first, then one full sweep (the sweep
-            # then depends on the collective - no overlap possible)
-            ring = jax.lax.dynamic_update_index_in_dim(
-                state.ring, mirror_prev, jnp.mod(t - 1, D), axis=0)
-            input_ex, input_in, arrived, gate_ovf = (
-                backend.sweep_with_stats(layout, w_native, ring, t))
+        with phase("sweep"):
+            if cfg.overlap:
+                # backend splits delays >= 2 (old ring, independent of the
+                # collective) from delay == 1 (the fresh exchange) when it
+                # can; otherwise it degrades to write-then-sweep
+                (input_ex, input_in, arrived, ring,
+                 gate_ovf) = backend.sweep_overlap_with_stats(
+                    layout, w_native, state.ring, t, mirror_prev)
+            else:
+                # naive schedule: write first, then one full sweep (the
+                # sweep then depends on the collective - no overlap
+                # possible)
+                ring = jax.lax.dynamic_update_index_in_dim(
+                    state.ring, mirror_prev, jnp.mod(t - 1, D), axis=0)
+                input_ex, input_in, arrived, gate_ovf = (
+                    backend.sweep_with_stats(layout, w_native, ring, t))
 
         # ---- (3) external drive + neuron dynamics ------------------------
-        key = jax.random.wrap_key_data(state.key)
-        key, sub = jax.random.split(key)
-        mkey = None
-        if model.stochastic:
-            # split ONLY for stochastic models (poisson emitters) -
-            # deterministic dynamics keep the pre-registry key stream
-            sub, mkey = jax.random.split(sub)
-            if state.drive_key is not None:
-                # decomposition-invariant drive: the shared stream keyed
-                # per neuron by GLOBAL id inside the model, not the
-                # per-shard split
-                mkey = jax.random.wrap_key_data(state.drive_key)
-        if cfg.engine.external_drive:
-            input_ex = input_ex + (
-                g["ext_weight"]
-                * poisson_events(sub, g["ext_thresh"], g["global_id"])
-            ).astype(dtype)
-        neurons = snn.NeuronState(
-            v_m=state.v_m, syn_ex=state.syn_ex, syn_in=state.syn_in,
-            ref_count=state.ref_count,
-            spike=jnp.zeros((n_local,), jnp.bool_), group_id=g["group_id"],
-            extra=dict(state.aux))
-        if state.neuron_model != model.name:
-            raise ValueError(
-                f"DistState was initialized for neuron_model="
-                f"{state.neuron_model!r} but cfg selects {model.name!r}; "
-                "re-init with init_stacked_state(neuron_model=...)")
-        model.check_state(neurons)
-        table = jnp.asarray(table_np, dtype)
-        neurons = backend.neuron_update(
-            layout, neurons, table, input_ex, input_in,
-            synapse_model=cfg.engine.synapse_model,
-            model=model, key=mkey, t=t, gid=g.get("global_id"),
-            surrogate=cfg.engine.surrogate)
+        with phase("neuron"):
+            key = jax.random.wrap_key_data(state.key)
+            key, sub = jax.random.split(key)
+            mkey = None
+            if model.stochastic:
+                # split ONLY for stochastic models (poisson emitters) -
+                # deterministic dynamics keep the pre-registry key stream
+                sub, mkey = jax.random.split(sub)
+                if state.drive_key is not None:
+                    # decomposition-invariant drive: the shared stream
+                    # keyed per neuron by GLOBAL id inside the model, not
+                    # the per-shard split
+                    mkey = jax.random.wrap_key_data(state.drive_key)
+            if cfg.engine.external_drive:
+                input_ex = input_ex + (
+                    g["ext_weight"]
+                    * poisson_events(sub, g["ext_thresh"], g["global_id"])
+                ).astype(dtype)
+            neurons = snn.NeuronState(
+                v_m=state.v_m, syn_ex=state.syn_ex, syn_in=state.syn_in,
+                ref_count=state.ref_count,
+                spike=jnp.zeros((n_local,), jnp.bool_),
+                group_id=g["group_id"], extra=dict(state.aux))
+            if state.neuron_model != model.name:
+                raise ValueError(
+                    f"DistState was initialized for neuron_model="
+                    f"{state.neuron_model!r} but cfg selects "
+                    f"{model.name!r}; re-init with "
+                    "init_stacked_state(neuron_model=...)")
+            model.check_state(neurons)
+            table = jnp.asarray(table_np, dtype)
+            neurons = backend.neuron_update(
+                layout, neurons, table, input_ex, input_in,
+                synapse_model=cfg.engine.synapse_model,
+                model=model, key=mkey, t=t, gid=g.get("global_id"),
+                surrogate=cfg.engine.surrogate)
         bits = neurons.spike
 
         # ---- (4) plasticity ----------------------------------------------
         if cfg.engine.stdp is not None:
-            traces = stdp_mod.TraceState(k_pre=state.k_pre,
-                                         k_post=state.k_post)
-            weights = backend.stdp_update(layout, w_native, arrived,
-                                          bits, traces, cfg.engine.stdp)
-            pre_arr = jax.ops.segment_max(
-                arrived, backend.edge_pre_index(layout),
-                num_segments=n_mirror)
-            traces = stdp_mod.update_traces(traces, cfg.engine.stdp,
-                                            cfg.engine.dt, pre_arr, bits)
-            k_pre, k_post = traces.k_pre, traces.k_post
-            if convert:  # scan carry keeps the state's own layout
-                weights = backends_mod.convert_weights(
-                    layout, weights, native_tag, state.weights_layout)
+            with phase("stdp"):
+                traces = stdp_mod.TraceState(k_pre=state.k_pre,
+                                             k_post=state.k_post)
+                weights = backend.stdp_update(layout, w_native, arrived,
+                                              bits, traces, cfg.engine.stdp)
+                pre_arr = jax.ops.segment_max(
+                    arrived, backend.edge_pre_index(layout),
+                    num_segments=n_mirror)
+                traces = stdp_mod.update_traces(traces, cfg.engine.stdp,
+                                                cfg.engine.dt, pre_arr, bits)
+                k_pre, k_post = traces.k_pre, traces.k_post
+                if convert:  # scan carry keeps the state's own layout
+                    weights = backends_mod.convert_weights(
+                        layout, weights, native_tag, state.weights_layout)
         else:
             # weights unchanged: carry the state's own vector (a round-trip
             # would cost two edge passes and zero flat padding slots)

@@ -72,7 +72,7 @@ from repro.core import snn
 from repro.core import stdp as stdp_mod
 
 __all__ = ["ShardGraph", "EngineConfig", "EngineState", "init_state",
-           "engine_step", "run", "synaptic_sweep",
+           "engine_step", "run", "synaptic_sweep", "PHASES", "phase",
            "state_with_weights_layout", "StepContext", "make_step_context",
            "make_step_fn", "make_session_step_fn", "stack_states",
            "slot_state", "set_slot_state", "masked_select",
@@ -356,6 +356,20 @@ def _diffusion_drive(key, graph: ShardGraph, dt: float, dtype):
 
 _DRIVES = {"poisson": _poisson_drive, "diffusion": _diffusion_drive}
 
+# The phases of a step, each a ``jax.named_scope`` around its part of
+# ``engine_step`` and ``distributed._build_step``'s ``step_local``.  XLA
+# keeps the scope in every compiled instruction's ``op_name`` metadata
+# (``.../while/body/closed_call/sweep/...``), which is how the chip
+# benchmark (``bench/phases.py``) times each phase in a profiler trace.
+PHASES = ("sweep", "neuron", "stdp", "exchange")
+
+
+def phase(name: str):
+    """The named scope of one of :data:`PHASES`."""
+    if name not in PHASES:
+        raise ValueError(f"unknown phase {name!r}; phases: {PHASES}")
+    return jax.named_scope(name)
+
 
 def engine_step(state: EngineState, graph: ShardGraph, table: jax.Array,
                 cfg: EngineConfig, *,
@@ -364,6 +378,12 @@ def engine_step(state: EngineState, graph: ShardGraph, table: jax.Array,
                 model: "neuron_models_mod.NeuronModel | None" = None):
     """One dt: sweep -> neuron update -> STDP -> ring write. Returns
     (new_state, spike_bits).
+
+    The work runs in three of the :data:`PHASES` scopes, the names the
+    chip benchmark reads: ``sweep`` (the synaptic sweep and the ring
+    write), ``neuron`` (the external drive and the neuron update) and
+    ``stdp`` (the weight update, the pre-trace ``segment_max``, the trace
+    update and the compatibility conversion back).
 
     ``backend``/``layout``/``model`` may be pre-resolved by callers that
     step in a loop (``run``); otherwise they derive from ``cfg``.
@@ -393,56 +413,61 @@ def engine_step(state: EngineState, graph: ShardGraph, table: jax.Array,
 
     # (1) synaptic sweep over owned edges (+ gate-saturation telemetry,
     #     a constant 0 on ungated backends)
-    input_ex, input_in, arrived, gate_ovf = backend.sweep_with_stats(
-        layout, w_native, state.ring, state.t)
+    with phase("sweep"):
+        input_ex, input_in, arrived, gate_ovf = backend.sweep_with_stats(
+            layout, w_native, state.ring, state.t)
     gate_prev = (state.gate_overflow if state.gate_overflow is not None
                  else jnp.zeros((), jnp.int32))
 
-    # (2) external stochastic drive
-    key, sub = jax.random.split(state.key)
-    mkey = None
-    if model.stochastic:
-        # split ONLY for stochastic models - deterministic dynamics keep
-        # the pre-registry key stream (the LIF bit-exactness pin).  When
-        # the state carries the shard-invariant drive key, model draws use
-        # THAT (per-neuron streams fold in t + global id); the split still
-        # happens so the ext-drive stream is unchanged either way.
-        sub, mkey = jax.random.split(sub)
-        if state.drive_key is not None:
-            mkey = state.drive_key
-    if cfg.external_drive and graph.ext_rate is not None:
-        if cfg.external_drive_mode not in _DRIVES:
-            raise ValueError(
-                f"unknown external_drive_mode {cfg.external_drive_mode!r};"
-                f" available: {sorted(_DRIVES)}")
-        drive = _DRIVES[cfg.external_drive_mode]
-        input_ex = input_ex + drive(sub, graph, cfg.dt, dtype)
+    with phase("neuron"):
+        # (2) external stochastic drive
+        key, sub = jax.random.split(state.key)
+        mkey = None
+        if model.stochastic:
+            # split ONLY for stochastic models - deterministic dynamics
+            # keep the pre-registry key stream (the LIF bit-exactness
+            # pin).  When the state carries the shard-invariant drive key,
+            # model draws use THAT (per-neuron streams fold in t + global
+            # id); the split still happens so the ext-drive stream is
+            # unchanged either way.
+            sub, mkey = jax.random.split(sub)
+            if state.drive_key is not None:
+                mkey = state.drive_key
+        if cfg.external_drive and graph.ext_rate is not None:
+            if cfg.external_drive_mode not in _DRIVES:
+                raise ValueError(
+                    f"unknown external_drive_mode "
+                    f"{cfg.external_drive_mode!r}; available: "
+                    f"{sorted(_DRIVES)}")
+            drive = _DRIVES[cfg.external_drive_mode]
+            input_ex = input_ex + drive(sub, graph, cfg.dt, dtype)
 
-    # (3) neuron dynamics (model-dispatched, DESIGN.md §12)
-    neurons = backend.neuron_update(layout, state.neurons, table, input_ex,
-                                    input_in, synapse_model=cfg.synapse_model,
-                                    model=model, key=mkey, t=state.t,
-                                    gid=graph.global_id,
-                                    surrogate=cfg.surrogate)
+        # (3) neuron dynamics (model-dispatched, DESIGN.md §12)
+        neurons = backend.neuron_update(
+            layout, state.neurons, table, input_ex, input_in,
+            synapse_model=cfg.synapse_model, model=model, key=mkey,
+            t=state.t, gid=graph.global_id, surrogate=cfg.surrogate)
     spike_bits = neurons.spike
 
     # (4) plasticity: weights first (traces exclude this step's spikes:
     #     all-pairs convention), then trace update.
     if cfg.stdp is not None:
-        weights = backend.stdp_update(layout, w_native, arrived,
-                                      spike_bits, state.traces, cfg.stdp)
-        # pre trace is indexed by ARRIVAL at the mirror (axonal delay folded
-        # in by reading the ring), so increment it with arrivals mapped back
-        # to mirrors (through the pre index matching ``arrived``'s layout);
-        # post trace with this step's spikes.
-        pre_arrived_mirror = jax.ops.segment_max(
-            arrived, backend.edge_pre_index(layout),
-            num_segments=graph.n_mirror)
-        traces = stdp_mod.update_traces(
-            state.traces, cfg.stdp, cfg.dt, pre_arrived_mirror, spike_bits)
-        if convert:  # keep the carried layout stable for scan/loop callers
-            weights = backends_mod.convert_weights(
-                layout, weights, native_tag, state.weights_layout)
+        with phase("stdp"):
+            weights = backend.stdp_update(layout, w_native, arrived,
+                                          spike_bits, state.traces, cfg.stdp)
+            # pre trace is indexed by ARRIVAL at the mirror (axonal delay
+            # folded in by reading the ring), so increment it with arrivals
+            # mapped back to mirrors (through the pre index matching
+            # ``arrived``'s layout); post trace with this step's spikes.
+            pre_arrived_mirror = jax.ops.segment_max(
+                arrived, backend.edge_pre_index(layout),
+                num_segments=graph.n_mirror)
+            traces = stdp_mod.update_traces(
+                state.traces, cfg.stdp, cfg.dt, pre_arrived_mirror,
+                spike_bits)
+            if convert:  # keep the carried layout stable for scan/loop callers
+                weights = backends_mod.convert_weights(
+                    layout, weights, native_tag, state.weights_layout)
     else:
         # weights unchanged: carry the state's own vector (never the
         # round-tripped one - that would cost two edge passes and zero the
@@ -452,10 +477,12 @@ def engine_step(state: EngineState, graph: ShardGraph, table: jax.Array,
     # (5) write this step's spikes into the ring at slot t % D.  In the
     # single-shard engine the mirror table is the identity over local
     # neurons; the distributed engine overrides this with exchanged bits.
-    local_bits = spike_bits.astype(dtype)
-    mirror_bits = jnp.take(local_bits, graph.mirror_src_idx)
-    ring = jax.lax.dynamic_update_index_in_dim(
-        state.ring, mirror_bits, jnp.mod(state.t, graph.max_delay), axis=0)
+    with phase("sweep"):
+        local_bits = spike_bits.astype(dtype)
+        mirror_bits = jnp.take(local_bits, graph.mirror_src_idx)
+        ring = jax.lax.dynamic_update_index_in_dim(
+            state.ring, mirror_bits, jnp.mod(state.t, graph.max_delay),
+            axis=0)
 
     new_state = EngineState(neurons=neurons, ring=ring, weights=weights,
                             traces=traces, t=state.t + 1, key=key,

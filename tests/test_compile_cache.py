@@ -18,7 +18,9 @@ import json, jax
 from repro.utils.compile_cache import use_compile_cache
 used = use_compile_cache()
 print(json.dumps({"used": used,
-                  "config": jax.config.jax_compilation_cache_dir}))
+                  "config": jax.config.jax_compilation_cache_dir,
+                  "metadata_in_key":
+                      jax.config.jax_compilation_cache_include_metadata_in_key}))
 """
 
 
@@ -35,7 +37,7 @@ def test_compile_cache_dir(tmp_path, from_env):
     res = json.loads(out.stdout.strip().splitlines()[-1])
     want = (str(tmp_path) if from_env
             else os.path.join(ROOT, ".jax_cache"))
-    assert res == {"used": want, "config": want}
+    assert res == {"used": want, "config": want, "metadata_in_key": True}
     if not from_env:
         with open(os.path.join(ROOT, ".gitignore")) as f:
             assert ".jax_cache/" in f.read().split()

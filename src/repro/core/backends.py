@@ -14,8 +14,11 @@ interchangeable implementations, the same engine-extraction move CoreNEURON
 made for NEURON (memory layout + compute engine swapped together under one
 network description):
 
-* ``flat``     - one fused gather + two ``segment_sum`` reductions (the
-                 TPU/XLA-idiomatic form; DESIGN.md §2);
+* ``flat``     - one fused gather + a per-row reduction (the TPU/XLA-
+                 idiomatic form; DESIGN.md §2): each row summed on its own
+                 where the builder found every row holding ``K >= 128``
+                 contiguous edges (``row_indegree``), else two
+                 ``segment_sum`` scatters;
 * ``bucketed`` - the paper's literal low-to-high delay sweep (a Fugaku
                  thread's schedule), kept as the structural cross-check;
 * ``pallas``   - the Pallas TPU kernels (``synaptic_gather``, ``lif_step``,
@@ -36,7 +39,7 @@ per-shard arrays (device side).  Static geometry (counts, block shapes)
 must be Python ints in both cases; array fields may be traced.
 
 Weight/arrivals layout (the blocked-resident hot path): a backend declares
-``weights_layout`` - ``"flat"`` (owner-sorted (E,), the default) or
+``weights_layout`` - ``"flat"`` ((delay, post)-sorted (E,), the default) or
 ``"blocked"`` (the ELL slot order, (NB*EB,)).  Run-time weights live in the
 backend's native layout inside engine/distributed state; ``edge_perm``
 conversions happen only at the build / checkpoint / telemetry boundaries
@@ -101,6 +104,9 @@ class EdgeLayout:
     plastic: Any       # (E,) bool
     bucket_ptr: np.ndarray | None = None
     blocked: BlockedGraph | None = None
+    #: K when edges ``[r*K, (r+1)*K)`` are exactly row ``r``'s (the
+    #: builder's ``row_indegree``); None under shard_map
+    row_indegree: int | None = None
 
     @property
     def n_edges(self) -> int:
@@ -116,6 +122,7 @@ def layout_of(graph) -> EdgeLayout:
         channel=graph.channel, plastic=graph.plastic,
         bucket_ptr=graph.bucket_ptr,
         blocked=getattr(graph, "blocked", None),
+        row_indegree=getattr(graph, "row_indegree", None),
     )
 
 
@@ -143,19 +150,89 @@ def _device_blocked(bg: BlockedGraph) -> BlockedGraph:
         )
 
 
-def _accumulate(layout: EdgeLayout, weights, arrived):
-    """Weighted per-edge arrivals -> (input_ex, input_in) via segment_sum.
+#: lanes of a TPU vector register.  A flat (E,) vector viewed as
+#: (E/LANES, LANES) keeps its memory layout; a (rows, K) view whose K is no
+#: multiple of it makes XLA copy the vector into a 2-D layout.
+LANES = 128
 
-    Race-free by construction: ``post_idx`` is owner-sorted, so this is the
-    vector analogue of "each thread owns its rows" (eq. 14).
+
+def _accumulate(layout: EdgeLayout, weights, arrived):
+    """Weighted per-edge arrivals -> (input_ex, input_in).
+
+    Race-free by construction: every edge adds only to its own post row,
+    the vector analogue of "each thread owns its rows" (eq. 14).  Edges
+    are ``(delay, post)``-sorted, so a row's edges are contiguous only
+    where the builder found them so (``row_indegree``): there, and for
+    rows of at least :data:`LANES` edges, each row is summed on its own
+    (:func:`_row_reduce`); elsewhere by two ``segment_sum`` scatters over
+    ``post_idx``.
     """
     contrib = weights * arrived
-    ex = jnp.where(layout.channel == 0, contrib, 0.0)
-    inh = jnp.where(layout.channel == 1, contrib, 0.0)
-    return (jax.ops.segment_sum(ex, layout.post_idx,
-                                num_segments=layout.n_local),
-            jax.ops.segment_sum(inh, layout.post_idx,
-                                num_segments=layout.n_local))
+    k = layout.row_indegree
+    if k is not None and k >= LANES:
+        with jax.named_scope("row_reduce"):
+            return _row_reduce(layout, contrib, k)
+    with jax.named_scope("segment_reduce"):
+        ex = jnp.where(layout.channel == 0, contrib, 0.0)
+        inh = jnp.where(layout.channel == 1, contrib, 0.0)
+        return (jax.ops.segment_sum(ex, layout.post_idx,
+                                    num_segments=layout.n_local),
+                jax.ops.segment_sum(inh, layout.post_idx,
+                                    num_segments=layout.n_local))
+
+
+def _row_reduce(layout: EdgeLayout, contrib, k: int):
+    """Per-row channel sums of a layout whose row ``r`` owns edges
+    ``[r*k, (r+1)*k)``, with no scatter and no copy of an edge vector.
+
+    The edges are viewed as chunks of :data:`LANES` (the last one padded
+    apart).  One pass over them sums each chunk per channel, split at the
+    one row start a chunk can hold (``k >= LANES``): the head (the lanes
+    before it) belongs to the row the chunk starts in, the tail to the
+    next row.  A row then adds the heads of the ``<= ceil(k / LANES)``
+    chunks that start in it and the tail of the chunk before them.  Rows
+    past the owned ones hold padding edges (arrived 0) or none, so they
+    sum to 0 as ``segment_sum`` gives them.
+    """
+    e = contrib.shape[0]
+    whole = e // LANES * LANES
+    heads, tails = _chunk_sums(contrib[:whole].reshape(-1, LANES),
+                               layout.channel[:whole].reshape(-1, LANES),
+                               0, k)
+    if whole < e:
+        pad = (0, whole + LANES - e)
+        h, t = _chunk_sums(jnp.pad(contrib[whole:], pad)[None],
+                           jnp.pad(layout.channel[whole:], pad)[None],
+                           whole, k)
+        heads = jnp.concatenate([heads, h])
+        tails = jnp.concatenate([tails, t])
+
+    m = heads.shape[0]
+    rows = min(layout.n_local, e // k)
+    # chunk ``start[r]`` is the first that starts in row r
+    start = (jnp.arange(rows + 1, dtype=jnp.int32) * k + LANES - 1) // LANES
+    idx = start[:-1, None] + jnp.arange(-(-k // LANES), dtype=jnp.int32)
+    inside = (idx < start[1:, None])[..., None]
+    sums = jnp.where(inside, heads[jnp.minimum(idx, m - 1)], 0).sum(1)
+    prev = start[:-1] - 1
+    sums = sums + jnp.where((prev >= 0)[:, None],
+                            tails[jnp.maximum(prev, 0)], 0)
+    sums = jnp.pad(sums, ((0, layout.n_local - rows), (0, 0)))
+    return sums[:, 0], sums[:, 1]
+
+
+def _chunk_sums(x, channel, offset: int, k: int):
+    """``(heads, tails)`` of the ``(m, LANES)`` chunks of edges that start
+    at edge ``offset``: each ``(m, 2)``, excitatory then inhibitory.  XLA
+    fuses the four sums into one pass over the edges."""
+    m = x.shape[0]
+    first = offset + jnp.arange(m, dtype=jnp.int32) * LANES
+    head = (jax.lax.broadcasted_iota(jnp.int32, (m, LANES), 1)
+            < ((first // k + 1) * k - first)[:, None])
+    ex = channel == 0
+    parts = [jnp.where(sel, x, 0).sum(1) for sel in
+             (ex & head, ~ex & head, ex & ~head, ~ex & ~head)]
+    return jnp.stack(parts[:2], 1), jnp.stack(parts[2:], 1)
 
 
 def _flat_arrivals(layout: EdgeLayout, ring, t):
@@ -439,10 +516,13 @@ class SweepBackend:
 
 
 class FlatBackend(SweepBackend):
-    """Fused-gather + segment_sum sweep - the XLA/TPU-idiomatic form: one
-    large vectorized gather beats a per-bucket loop on a systolic/vector
-    machine, and sparsity is exploited through zero values rather than
-    skipped work (DESIGN.md §2)."""
+    """Fused-gather + per-row reduction sweep - the XLA/TPU-idiomatic
+    form: one large vectorized gather beats a per-bucket loop on a
+    systolic/vector machine, and sparsity is exploited through zero values
+    rather than skipped work (DESIGN.md §2).  The reduction sums each row
+    on its own where the layout's ``row_indegree`` is at least
+    :data:`LANES` (a single-delay, fixed-indegree graph on one shard) and
+    runs two ``segment_sum`` scatters elsewhere, under shard_map too."""
 
     name = "flat"
 

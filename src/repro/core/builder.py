@@ -47,8 +47,8 @@ from repro.core.snn import LIFParams
 
 __all__ = ["Population", "Projection", "NetworkSpec", "build_shards",
            "decompose", "shard_edge_counts", "shard_row_degrees",
-           "procedural_shard_raw", "finalize_shards", "spec_to_dict",
-           "spec_from_dict"]
+           "procedural_shard_raw", "finalize_shards", "row_indegree",
+           "spec_to_dict", "spec_from_dict"]
 
 # distinct from the materialized pipeline's per-projection salt (7919) so the
 # two stream families can never collide
@@ -534,6 +534,31 @@ def _pad_up(n, m):
     return ((n + m - 1) // m) * m
 
 
+def row_indegree(post_idx: np.ndarray, delay: np.ndarray,
+                 n_owned: int) -> int | None:
+    """``K`` if the live edges (``delay > 0``) form a prefix of the edge
+    arrays, ``post_idx`` over them is non-decreasing and each of the
+    ``n_owned`` rows holds exactly ``K`` of them (padding rows none);
+    else None.
+
+    Row ``r``'s inputs are then edges ``[r*K, (r+1)*K)``, which lets the
+    flat sweep sum each row on its own.  The builder's ``(delay, post)``
+    order keeps a row's edges together only where they share one delay,
+    so a graph whose rows mix delays, or differ in size, reads None.
+    """
+    live = delay > 0
+    e = int(np.count_nonzero(live))
+    if n_owned == 0 or e == 0 or e % n_owned or not live[:e].all():
+        return None
+    post = post_idx[:e]
+    if np.any(post[1:] < post[:-1]):
+        return None
+    counts = np.bincount(post, minlength=n_owned)
+    if counts.size != n_owned or np.any(counts != e // n_owned):
+        return None
+    return e // n_owned
+
+
 def finalize_shards(spec: NetworkSpec, dec: Decomposition, raw: list, *,
                     pad_to_multiple: int = 8,
                     uniform_pad: bool = True,
@@ -614,6 +639,7 @@ def finalize_shards(spec: NetworkSpec, dec: Decomposition, raw: list, *,
             # GLOBAL neuron ids of the owned rows (-1 on padding): the
             # decomposition-invariant key for stochastic per-neuron draws
             global_id=pad(r["owned"].astype(np.int32), n_local_pad, fill=-1),
+            row_indegree=row_indegree(post_l, d, n_loc),
         ))
         raw[i] = None  # free the compact arrays as we go
 

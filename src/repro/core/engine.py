@@ -34,7 +34,7 @@ model's ``extra`` state vars, struct-checked at trace time.
 
 The hot path (sweep, neuron update, STDP edge update) dispatches through the
 execution-backend registry of :mod:`repro.core.backends` (DESIGN.md §9):
-``EngineConfig.sweep`` selects ``"flat"`` (fused gather + segment_sum, the
+``EngineConfig.sweep`` selects ``"flat"`` (fused gather + per-row sums, the
 TPU/XLA-idiomatic form), ``"bucketed"`` (the paper's literal low-to-high
 delay sweep, the structural cross-check), or ``"pallas"`` (the TPU kernels
 on the post-block ELL layout; interpret mode off-TPU; ``"pallas:auto"``
@@ -52,7 +52,7 @@ disagree (the compatibility path; pass ``sweep=`` to ``init_state`` to
 avoid it in hand-rolled step loops).
 
 Writes are conflict-free by construction: every backend reduces over
-owner-sorted ``post_idx`` rows it exclusively owns - the vector analogue of
+``post_idx`` rows it exclusively owns - the vector analogue of
 "each thread owns its rows" (eq. 14).
 """
 
@@ -107,6 +107,11 @@ class ShardGraph:
     # Post-block ELL twin of the flat arrays (repro.core.layout.BlockedGraph),
     # emitted natively by the builder; consumed by the pallas backend.
     blocked: Any = None
+    # K when the live edges form a post-sorted prefix in which every owned
+    # row holds exactly K edges (builder.row_indegree), else None: row r
+    # then owns edges [r*K, (r+1)*K), and the flat sweep sums each row on
+    # its own in place of two segment_sum scatters (backends._row_reduce).
+    row_indegree: int | None = None
 
     @property
     def n_edges(self) -> int:

@@ -5,21 +5,26 @@ topology, at the widths the chip runs: ``hpc_benchmark(scale=1)`` on one
 chip and one shard of ``marmoset(scale=0.05)`` on a 2x2 mesh.  Interpret
 mode cannot see what these catch - blocks that break the (8, 128) tiling
 rule, gathers the kernel compiler does not lower, VMEM overflow.  Each
-test checks that the compiled program holds the kernel
-(``tpu_custom_call``), i.e. nothing fell back to interpret mode.
+kernel test checks that the compiled program holds the kernel
+(``tpu_custom_call``), i.e. nothing fell back to interpret mode.  The
+flat sweep's step is compiled whole at the benchmark's size, where XLA's
+layout choices decide its scratch memory.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU compiler library at a time.
 """
 
+import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import snn
+from repro.core import engine, neuron_models, snn
 from repro.core.models import HPC_STDP
 from repro.kernels import adex_step, izhikevich_step
 from repro.kernels.lif_step import lif_step_kernel
@@ -137,3 +142,86 @@ def test_blocked_reduce_sweep_compiles(shape, width):
                           interpret=False),
         shape((nb, eb), i32), shape((nb, eb), f32), shape((nb, eb), f32),
         shape((nb, eb), i32))
+
+
+# hpc_benchmark at the benchmark's scale 0.1 on one chip: 1,125 rows of
+# 11,250 edges (no multiple of the 128 lanes), padded to 1,128 rows and
+# 12,656,256 edges; one delay of 15 steps, D = 16.
+ROWS, N_LOCAL, K, E_PAD, D = 1125, 1128, 11250, 12656256, 16
+
+
+def _flat_run_text(shape, row_indegree, stdp):
+    """Compiled text and temp bytes of ``engine.run`` (10 steps, flat
+    sweep) with the graph's arrays as operands, as the benchmark runs it."""
+    edge = {k: shape((E_PAD,), dt) for k, dt in (
+        ("pre_idx", i32), ("post_idx", i32), ("delay", i32),
+        ("channel", i32), ("plastic", jnp.bool_))}
+    rows = {k: shape((N_LOCAL,), dt) for k, dt in (
+        ("mirror_src_shard", i32), ("mirror_src_idx", i32),
+        ("group_id", i32), ("ext_weight", f32), ("global_id", i32))}
+    gid = np.where(np.arange(N_LOCAL) < ROWS, np.arange(N_LOCAL), -1)
+    static = engine.ShardGraph(
+        n_local=N_LOCAL, n_mirror=N_LOCAL, max_delay=D, pre_idx=None,
+        post_idx=None, delay=None, channel=None, plastic=None,
+        weight_init=np.zeros(E_PAD, np.float32),
+        bucket_ptr=np.zeros(D + 2, np.int64), mirror_src_shard=None,
+        mirror_src_idx=None, group_id=np.zeros(N_LOCAL, np.int32),
+        ext_rate=np.full(N_LOCAL, 2e4, np.float32), ext_weight=None,
+        global_id=gid.astype(np.int32), row_indegree=row_indegree)
+    groups = [snn.LIFParams()]
+    state = jax.eval_shape(lambda: engine.init_state(
+        static, groups, jax.random.key(0)))
+    state = jax.tree.map(lambda a: shape(a.shape, a.dtype), state)
+    static = dataclasses.replace(static, global_id=None, weight_init=None)
+    table = neuron_models.get_model("lif").make_param_table(groups, 0.1)
+    cfg = engine.EngineConfig(dt=0.1, stdp=HPC_STDP if stdp else None)
+
+    def call(state, ops):
+        ops = dict(ops)
+        tab = ops.pop("table")
+        return engine.run(state, dataclasses.replace(static, **ops), tab,
+                          cfg, 10)
+
+    ops = dict(edge, **rows, table=shape(table.shape, table.dtype))
+    compiled = jax.jit(call).lower(state, ops).compile()
+    return compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+
+
+@pytest.mark.parametrize("stdp", [False, True], ids=["static", "stdp"])
+def test_flat_sweep_row_reduce_compiles_without_relayout(shape, stdp):
+    """On a fixed-indegree graph the compiled step sums rows in one pass
+    over the edges with no scatter in the ``sweep`` scope, copies no edge
+    vector outside a fusion, and needs no more scratch memory than the
+    segment_sum form: a (rows, K) view of the flat edge vectors would copy
+    each one into a 2-D layout."""
+    text, temp = _flat_run_text(shape, K, stdp)
+    # one pass over the edges: a single fusion yields all four per-chunk
+    # sums (two channels, either side of a row start)
+    chunk_sums = [line for line in text.splitlines()
+                  if " fusion(" in line and "row_reduce/" in line
+                  and f"f32[{E_PAD // 128}]" in line.split(" fusion(")[0]]
+    assert len(chunk_sums) == 1, chunk_sums
+    assert chunk_sums[0].split(" fusion(")[0].count(
+        f"f32[{E_PAD // 128}]") == 4
+    in_sweep = re.compile(r'op_name="[^"]*/sweep/')
+    assert not [line for line in text.splitlines()
+                if " scatter(" in line and in_sweep.search(line)]
+    fused = set(re.findall(r"fusion\(.*calls=%([\w.\-]+)", text))
+    array = re.compile(r"= \w+\[([\d,]+)\]\S* ([\w\-]+)\(")
+    comp = None
+    for line in text.splitlines():
+        header = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if header:
+            comp = header.group(1)
+            continue
+        m = array.search(line)
+        if not m or comp in fused:
+            continue
+        dims = [int(d) for d in m.group(1).split(",")]
+        if np.prod(dims) >= ROWS * K:   # an edge vector, outside a fusion
+            # XLA's relayouts into 2-D carry no op_name of their own
+            assert len(dims) == 1 or m.group(2) == "bitcast", line
+            assert not (in_sweep.search(line)
+                        and m.group(2) in ("copy", "transpose")), line
+    _, temp_segment = _flat_run_text(shape, None, stdp)
+    assert temp <= temp_segment + 2**20
